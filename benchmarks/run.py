@@ -22,6 +22,9 @@ def main() -> None:
                          "shard_scale|bench_e2e|kernel|roofline")
     args = ap.parse_args()
     quick = not args.full
+    from repro.kernels.platform import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = []
     if args.only in (None, "kernel"):

@@ -35,6 +35,14 @@ Residency rules (cross-pane micro-batching support):
   the duration of the flush instead of round-tripping through
   ``np.asarray`` per bucket.  Host staging is *not* reused here: device
   transfers may be asynchronous, so inputs get fresh buffers.
+
+Device boundary (jax/pallas backends): each launch is staged on the host
+at a padded shape — the batch to the next power of two, masked bursts to
+the kernel's 128-row tile — so a stream compiles a bounded set of
+programs, and a warm pass over shapes already seen compiles nothing.
+Padding is exact (zero rows and zero mask columns propagate to zeros).
+Operands cross in ``platform.device_dtype`` (f32 on a TPU) by one explicit
+cast here, and results come back into the jobs' host dtype.
 """
 
 from __future__ import annotations
@@ -44,6 +52,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..kernels import ops
+from ..kernels.platform import device_dtype
 from ..obs.metrics import OCCUPANCY_BUCKETS
 
 __all__ = ["PropagateJob", "PaneBatchExecutor"]
@@ -125,8 +134,11 @@ class PaneBatchExecutor:
         for (bucket, shape, sl, _), host in zip(launched, outs):
             arr = full.get(id(bucket))
             if arr is None:
-                arr = full[id(bucket)] = np.empty(shape, dtype=host.dtype)
-            arr[sl] = host
+                arr = full[id(bucket)] = np.empty(
+                    shape, dtype=np.promote_types(host.dtype,
+                                                  bucket[0].base.dtype))
+            # drop the device path's padding (a no-op view on numpy)
+            arr[sl] = host[:len(bucket[sl]), :shape[1], :shape[2]]
         done: set[int] = set()
         for bucket, _, _, _ in launched:
             if id(bucket) in done:
@@ -147,14 +159,34 @@ class PaneBatchExecutor:
     def _stage(self, kind: str, nb: int, item_shape: tuple,
                dtype) -> np.ndarray:
         """A reusable stacked staging buffer (numpy backend only)."""
-        if self.backend != "np":
-            return np.empty((nb,) + item_shape, dtype=dtype)
         key = (kind,) + item_shape + (np.dtype(dtype),)
         buf = self._staging.get(key)
         if buf is None or buf.shape[0] < nb:
             buf = np.empty((nb,) + item_shape, dtype=dtype)
             self._staging[key] = buf
         return buf[:nb]
+
+    def _device_stack(self, items: list[PropagateJob], b_pad: int,
+                      masked: bool) -> tuple:
+        """Stage one device launch: zero-padded to a power-of-two batch and
+        ``b_pad`` rows, cast to the device dtype (see module doc)."""
+        nb = _next_pow2(len(items))
+        d = items[0].base.shape[1]
+        base = np.zeros((nb, b_pad, d), device_dtype(items[0].base.dtype))
+        for i, j in enumerate(items):
+            base[i, :j.base.shape[0]] = j.base
+        if not masked:
+            return (base,)
+        mask = np.zeros((nb, b_pad, b_pad), base.dtype)
+        for i, j in enumerate(items):
+            b = j.mask.shape[0]
+            mask[i, :b, :b] = j.mask
+        return base, mask
+
+    def _count(self, kind: str) -> None:
+        self.launches += 1
+        if self.obs is not None:
+            self.obs.count(f"batch_exec.launches.{kind}")
 
     def _launch_dense(self, jobs: list[PropagateJob]) -> list:
         buckets: dict[tuple, list[PropagateJob]] = {}
@@ -167,13 +199,22 @@ class PaneBatchExecutor:
             if self.obs is not None:
                 self.obs.observe("batch_exec.bucket_occupancy", nb,
                                  OCCUPANCY_BUCKETS)
+            if self.backend != "np":
+                for sl in self._slices(nb):
+                    self._count("dense")
+                    launched.append((bucket, (nb, bp, d), sl,
+                                     ops.propagate_dense_batched(
+                                         *self._device_stack(bucket[sl], bp,
+                                                             False),
+                                         backend=self.backend)))
+                continue
             stacked = self._stage("dense", nb, (bp, d), dtype)
             for i, j in enumerate(bucket):
                 bj = j.base.shape[0]
                 stacked[i, :bj] = j.base
                 stacked[i, bj:] = 0.0
             for sl in self._slices(nb):
-                self.launches += 1
+                self._count("dense")
                 launched.append((bucket, (nb, bp, d), sl,
                                  ops.propagate_dense_batched(
                                      stacked[sl], backend=self.backend)))
@@ -191,14 +232,24 @@ class PaneBatchExecutor:
             if self.obs is not None:
                 self.obs.observe("batch_exec.bucket_occupancy", nb,
                                  OCCUPANCY_BUCKETS)
+            if self.backend != "np":
+                b_pad = -(-b // ops.MASKED_TILE) * ops.MASKED_TILE
+                for sl in self._slices(nb):
+                    self._count("masked")
+                    launched.append((bucket, (nb, b, d), sl,
+                                     ops.propagate_batched(
+                                         *self._device_stack(bucket[sl],
+                                                             b_pad, True),
+                                         backend=self.backend)))
+                continue
             base = self._stage("mbase", nb, (b, d), dtype)
             mask = self._stage("mmask", nb, (b, b), bucket[0].mask.dtype)
             for i, j in enumerate(bucket):
                 base[i] = j.base
                 mask[i] = j.mask
-            small = self.backend == "np" and b < _FAST_MIN_B
+            small = b < _FAST_MIN_B
             for sl in self._slices(nb):
-                self.launches += 1
+                self._count("masked")
                 if small:
                     # stacked row-loop oracle: b row steps for the whole
                     # bucket, each slice bitwise equal to the per-burst call

@@ -72,7 +72,9 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..kernels import ops
+from ..kernels.platform import device_dtype
 from ..obs.metrics import OCCUPANCY_BUCKETS
+from .batch_exec import _next_pow2
 
 __all__ = ["FoldExecutor", "FoldJob", "FoldSchedule", "build_fold_schedule"]
 
@@ -307,12 +309,13 @@ class _ScanProgram:
     per-flush ``S`` block is structural, so it is built once per flush plan
     and stays on device across flushes."""
 
-    Z0: object             # [J*k*R + 1, C] fresh state + scratch row
+    Z0: object             # [>= J*k*R + 1, C] fresh state + scratch row
     PTM: object            # [rounds, NMAX, t]
     GQ: object             # [rounds, NMAX, R]
     SIDX: object           # [rounds, NMAX, n_used]
     SC: object             # [rounds, NMAX * n_used]
     ER: object             # [rounds, NMAX * n_used]
+    s_rows: int            # padded S row count (zero rows past s_flat)
     nu: int
     t: int
     n_used: int
@@ -563,7 +566,13 @@ class FoldExecutor:
         and park them on device.  Padded lanes read the scratch state row
         and the zero ``S`` row and scatter back to the scratch row, so any
         NaN/inf they produce (0 * inf from overflow-regime garbage) never
-        reaches a real state row."""
+        reaches a real state row.
+
+        Every operand extent (rounds, lanes, state and ``S`` rows) is
+        padded to a power of two, so flush plans share a bounded set of
+        compiled scan programs; padded rounds are all padded lanes, and
+        state rows past the scratch row are never addressed.  Floating
+        operands cross in ``platform.device_dtype``."""
         import jax
 
         nu, t, C = ctx.nu, len(ctx.pos_type_ids), ctx.layout.size
@@ -571,11 +580,13 @@ class FoldExecutor:
         R = 1 + nu * t + nu
         n_used = len(fp.rounds[0].buckets[0].used)
         scratch = J * k * R
-        n_s = fp.s_flat.shape[0]       # the appended zero S row's index
-        nr = len(fp.rounds)
-        nmax = max(len(rd.buckets[0].flat_gq) for rd in fp.rounds)
+        n_s = fp.s_flat.shape[0]       # the first zero S row's index
+        nr = _next_pow2(len(fp.rounds))
+        nmax = _next_pow2(max(len(rd.buckets[0].flat_gq)
+                              for rd in fp.rounds))
+        fdt = device_dtype(np.float64)
         GQ = np.full((nr, nmax, R), scratch, dtype=np.int32)
-        PTM = np.zeros((nr, nmax, t))
+        PTM = np.zeros((nr, nmax, t), fdt)
         SIDX = np.full((nr, nmax, n_used), n_s, dtype=np.int32)
         SC = np.full((nr, nmax * n_used), scratch, dtype=np.int32)
         ER = np.full((nr, nmax * n_used), scratch, dtype=np.int32)
@@ -593,11 +604,12 @@ class FoldExecutor:
                     ER[r, :nm * n_used] = rows
                 else:
                     ER[r, :nm * n_used][em] = rows
-        Z0 = np.concatenate([_state0(ctx, J).reshape(-1, C),
-                             np.zeros((1, C))])
+        Z0 = np.zeros((_next_pow2(scratch + 1), C), fdt)
+        Z0[:scratch] = _state0(ctx, J).reshape(-1, C)
         dp = jax.device_put
         return _ScanProgram(Z0=dp(Z0), PTM=dp(PTM), GQ=dp(GQ),
                             SIDX=dp(SIDX), SC=dp(SC), ER=dp(ER),
+                            s_rows=_next_pow2(n_s + 1),
                             nu=nu, t=t, n_used=n_used, J=J, k=k, R=R, C=C)
 
     @staticmethod
@@ -698,11 +710,16 @@ class FoldExecutor:
             self.obs.observe("fold_exec.bucket_occupancy",
                              max(len(rd.buckets[0].flat_gq)
                                  for rd in fp.rounds), OCCUPANCY_BUCKETS)
-        S_pad = np.concatenate([S_flat, np.zeros((1, S_flat.shape[1]))])
+        S_pad = np.zeros((sp.s_rows, S_flat.shape[1]),
+                         device_dtype(S_flat.dtype))
+        S_pad[:len(S_flat)] = S_flat
         Zf = ops.fold_rounds_scan(sp.Z0, S_pad, sp.PTM, sp.GQ, sp.SIDX,
                                   sp.SC, sp.ER, nu=sp.nu, t=sp.t,
                                   n_used=sp.n_used)
-        Z = np.asarray(Zf)[:-1].reshape(sp.J, sp.k, sp.R, sp.C)
+        n = sp.J * sp.k * sp.R
+        # back to the f64 host state (see _state0); the device may hold f32
+        Z = np.asarray(Zf)[:n].astype(np.float64, copy=False).reshape(
+            sp.J, sp.k, sp.R, sp.C)
         return _CtxState(ctx, cjobs, Z=Z)
 
     def _run_fast(self, st: _CtxState, fp: _FlushPlan,
@@ -924,10 +941,21 @@ class FoldExecutor:
             self.window_folds += 1
             U0 = np.stack([folds[i][0] for i in idxs])
             Mstack = np.stack([np.stack(folds[i][1]) for i in idxs])
+            if self.backend != "np":
+                # device launch: batch padded to a power of two (zero chains
+                # fold to zero), operands in the device dtype
+                U0, Mstack = _device_batch(U0), _device_batch(Mstack)
             raw.append((idxs, ops.fold_stacked(U0, Mstack,
                                                backend=self.backend)))
         for (idxs, _u), host in zip(raw,
                                     ops.device_get_all([u for _, u in raw])):
             for r, i in enumerate(idxs):
-                out[i] = host[r]
+                out[i] = host[r].astype(folds[i][0].dtype, copy=False)
         return out
+
+
+def _device_batch(x: np.ndarray) -> np.ndarray:
+    out = np.zeros((_next_pow2(len(x)),) + x.shape[1:],
+                   device_dtype(x.dtype))
+    out[:len(x)] = x
+    return out

@@ -82,9 +82,7 @@ def pipelined_apply(layer_fn, stacked_params, x, *, mesh: Mesh,
         return jax.lax.psum(out, stage_axis)
 
     pspec_params = jax.tree.map(lambda _: P(stage_axis), stacked_params)
-    from .compat import shard_map
-
-    f = shard_map(stage_fn, mesh=mesh,
+    f = jax.shard_map(stage_fn, mesh=mesh,
                   in_specs=(pspec_params, P()),
                   out_specs=P(), check_vma=False)
     return f(stacked_params, x)

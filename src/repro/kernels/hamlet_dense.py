@@ -27,7 +27,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
+from .hamlet_propagate import _I0, _dot, _require_32bit
+from .platform import interpret_kernels
 
 __all__ = ["dense_propagate_pallas"]
 
@@ -42,19 +43,31 @@ def _dense_kernel(k_ref, pow2_ref, rowpow_ref, base_ref, out_ref, s_ref,
 
     b = base_ref[0].astype(jnp.float32)                 # [T, d]
     K = k_ref[...]                                      # [T, T]
-    c = b + jnp.dot(K, b, preferred_element_type=jnp.float32)
+    c = b + _dot(K, b, jnp.float32)
     c = c + pow2_ref[...].T * s_ref[...]                # s_in * 2^i
     out_ref[0] = c.astype(out_ref.dtype)
     s_new = ((2.0 ** tile) * s_ref[...] +
-             jnp.dot(rowpow_ref[...], b, preferred_element_type=jnp.float32))
+             _dot(rowpow_ref[...], b, jnp.float32))
     s_ref[...] = s_new
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def dense_propagate_pallas(base: jax.Array, *, tile: int = 64,
-                           interpret: bool = True) -> jax.Array:
-    """base [nb, b, d] with b % tile == 0; returns the dense-burst counts."""
+                           interpret: bool | None = None) -> jax.Array:
+    """base [nb, b, d] with b % tile == 0; returns the dense-burst counts.
+
+    ``interpret=None`` follows the platform (see
+    :func:`~repro.kernels.hamlet_propagate.masked_prefix_propagate_pallas`).
+    """
+    if interpret is None:
+        interpret = interpret_kernels()
+    return _dense(base, tile=tile, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _dense(base, *, tile: int, interpret: bool):
     nb, b, d = base.shape
+    if not interpret:
+        _require_32bit(base)
     if b % tile:
         raise ValueError(f"b={b} must be a multiple of tile={tile}")
     if tile > 64:
@@ -72,15 +85,15 @@ def dense_propagate_pallas(base: jax.Array, *, tile: int = 64,
         kernel,
         grid=(nb, n_tiles),
         in_specs=[
-            pl.BlockSpec((tile, tile), lambda bi, t: (0, 0)),
-            pl.BlockSpec((1, tile), lambda bi, t: (0, 0)),
-            pl.BlockSpec((1, tile), lambda bi, t: (0, 0)),
-            pl.BlockSpec((1, tile, d), lambda bi, t: (bi, t, 0)),
+            pl.BlockSpec((tile, tile), lambda bi, t: (_I0, _I0)),
+            pl.BlockSpec((1, tile), lambda bi, t: (_I0, _I0)),
+            pl.BlockSpec((1, tile), lambda bi, t: (_I0, _I0)),
+            pl.BlockSpec((1, tile, d), lambda bi, t: (bi, t, _I0)),
         ],
-        out_specs=pl.BlockSpec((1, tile, d), lambda bi, t: (bi, t, 0)),
+        out_specs=pl.BlockSpec((1, tile, d), lambda bi, t: (bi, t, _I0)),
         out_shape=jax.ShapeDtypeStruct((nb, b, d), base.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(K, jnp.float32), jnp.asarray(pow2), jnp.asarray(rowpow),

@@ -5,6 +5,13 @@ to the numpy oracle / jnp reference / Pallas kernel, and unpads.  The engine
 uses ``backend="np"`` for small host-side bursts and the accelerator backends
 for large panes; the dry-run lowers the jnp/pallas paths on the production
 mesh.
+
+Every device entry is one jitted program per input shape.  The executors
+keep that set bounded by padding on the host before the call
+(``core/batch_exec.py``: ``nb`` to a power of two, masked ``b`` to the
+128-row tile); padding is exact because zero rows propagate to zeros.
+Whether a Pallas kernel compiles or interprets is the platform's call
+(``platform.interpret_kernels``), not the caller's.
 """
 
 from __future__ import annotations
@@ -16,7 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref
+from .hamlet_dense import dense_propagate_pallas
 from .hamlet_propagate import masked_prefix_propagate_pallas
+from .platform import interpret_kernels
 
 __all__ = ["propagate", "propagate_batched", "propagate_dense",
            "propagate_dense_batched", "fold_stacked", "fold_rounds_scan",
@@ -29,6 +38,11 @@ DENSE_B_MAX = 512
 PROPAGATE_BACKENDS = ("np", "jax", "jax_blocked", "jax_solve", "pallas")
 
 _LANE = 128
+# f32 matmuls at full precision on a TPU (its default pass is bf16); a
+# no-op for the CPU backend
+_HIGHEST = jax.lax.Precision.HIGHEST
+# row tile of the masked kernel; the executor pads masked buckets to it
+MASKED_TILE = 128
 
 
 def _pad_to(x: np.ndarray | jax.Array, axis: int, mult: int):
@@ -47,12 +61,25 @@ def _pallas_padded(base, mask, tile, interpret):
     base, d = _pad_to(base, 2, _LANE)
     mask, _ = _pad_to(mask, 1, tile)
     mask, _ = _pad_to(mask, 2, tile)
-    out = masked_prefix_propagate_pallas(base, mask, tile=tile, interpret=interpret)
+    out = masked_prefix_propagate_pallas(base, mask, tile=tile,
+                                         interpret=interpret)
     return out[:, :b, :d]
 
 
-def propagate_batched(base, mask, *, backend: str = "np", tile: int = 128,
-                      interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _dense_pallas_padded(base, tile, interpret):
+    x, b = _pad_to(base, 1, tile)
+    x, d = _pad_to(x, 2, _LANE)
+    out = dense_propagate_pallas(x, tile=tile, interpret=interpret)
+    return out[:, :b, :d]
+
+
+_jax_batched = jax.jit(jax.vmap(ref.masked_prefix_propagate_ref))
+_jax_dense_batched = jax.jit(jax.vmap(ref.prefix_propagate_dense))
+
+
+def propagate_batched(base, mask, *, backend: str = "np",
+                      tile: int = MASKED_TILE):
     """Batched propagation: base [nb, b, d], mask [nb, b, b] -> [nb, b, d].
 
     The batch is ragged-friendly at the edges: ``nb == 0`` returns an empty
@@ -75,8 +102,7 @@ def propagate_batched(base, mask, *, backend: str = "np", tile: int = 128,
         return np.stack([ref.numpy_prefix_propagate(base[i], mask[i])
                          for i in range(base.shape[0])])
     if backend == "jax":
-        return jax.vmap(ref.masked_prefix_propagate_ref)(jnp.asarray(base),
-                                                         jnp.asarray(mask))
+        return _jax_batched(jnp.asarray(base), jnp.asarray(mask))
     if backend == "jax_blocked":
         base = jnp.asarray(base)
         mask = jnp.asarray(mask)
@@ -88,7 +114,8 @@ def propagate_batched(base, mask, *, backend: str = "np", tile: int = 128,
         return jax.vmap(ref.masked_prefix_propagate_solve)(jnp.asarray(base),
                                                            jnp.asarray(mask))
     if backend == "pallas":
-        return _pallas_padded(jnp.asarray(base), jnp.asarray(mask), tile, interpret)
+        return _pallas_padded(jnp.asarray(base), jnp.asarray(mask), tile,
+                              interpret_kernels())
     raise ValueError(f"unknown backend {backend!r}; use one of {PROPAGATE_BACKENDS}")
 
 
@@ -148,7 +175,8 @@ def _fold_stacked_scan(U, Ms_t):
     """``u = u @ M.T`` chain as one scanned program; ``Ms_t [n, N, C, C]``."""
 
     def step(u, M):
-        return jnp.matmul(u[:, None, :], jnp.swapaxes(M, 1, 2))[:, 0], None
+        return jnp.matmul(u[:, None, :], jnp.swapaxes(M, 1, 2),
+                          precision=_HIGHEST)[:, 0], None
 
     u, _ = jax.lax.scan(step, U, Ms_t)
     return u
@@ -189,10 +217,11 @@ def fold_rounds_scan(Z0, S, PTM, GQ, SIDX, SC, ER, *, nu, t, n_used):
         gq, sidx, sc, er, ptm = xs
         zm = Zf[gq]                                       # [NMAX, R, C]
         Wu = jnp.matmul(ptm[:, None, None, :],
-                        zm[:, 1:1 + nu * t].reshape(-1, nu, t, C))[:, :, 0, :]
+                        zm[:, 1:1 + nu * t].reshape(-1, nu, t, C),
+                        precision=_HIGHEST)[:, :, 0, :]
         W = jnp.concatenate([zm[:, 0:1], Wu], axis=1)     # [NMAX, 1+nu, C]
         S_m = S[sidx]                                     # [NMAX, n_used, 1+nu]
-        upd = jnp.matmul(S_m, W).reshape(-1, C)
+        upd = jnp.matmul(S_m, W, precision=_HIGHEST).reshape(-1, C)
         Zf = Zf.at[sc].add(upd)
         return Zf.at[er].add(upd), None
 
@@ -200,11 +229,9 @@ def fold_rounds_scan(Z0, S, PTM, GQ, SIDX, SC, ER, *, nu, t, n_used):
     return Zf
 
 
-def propagate(base, mask, *, backend: str = "np", tile: int = 128,
-              interpret: bool = True):
+def propagate(base, mask, *, backend: str = "np", tile: int = MASKED_TILE):
     """Unbatched propagation: base [b, d], mask [b, b] -> [b, d]."""
-    out = propagate_batched(base[None], mask[None], backend=backend, tile=tile,
-                            interpret=interpret)
+    out = propagate_batched(base[None], mask[None], backend=backend, tile=tile)
     return out[0]
 
 
@@ -222,8 +249,7 @@ def propagate_dense(base, *, backend: str = "np"):
     return ref.prefix_propagate_dense(jnp.asarray(base))
 
 
-def propagate_dense_batched(base, *, backend: str = "np", tile: int = 64,
-                            interpret: bool = True):
+def propagate_dense_batched(base, *, backend: str = "np", tile: int = 64):
     """Batched dense-burst propagation: base [nb, b, d] -> [nb, b, d].
 
     One launch for a whole size bucket of dense bursts.  ``nb == 0`` returns
@@ -243,13 +269,8 @@ def propagate_dense_batched(base, *, backend: str = "np", tile: int = 64,
     if backend == "np":
         return ref.prefix_propagate_dense_np_batched(np.asarray(base))
     if backend in ("jax", "jax_blocked", "jax_solve"):
-        return jax.vmap(ref.prefix_propagate_dense)(jnp.asarray(base))
+        return _jax_dense_batched(jnp.asarray(base))
     if backend == "pallas":
-        from .hamlet_dense import dense_propagate_pallas
-
-        x = jnp.asarray(base)
-        x, b_real = _pad_to(x, 1, tile)
-        x, d_real = _pad_to(x, 2, _LANE)
-        out = dense_propagate_pallas(x, tile=tile, interpret=interpret)
-        return out[:, :b_real, :d_real]
+        return _dense_pallas_padded(jnp.asarray(base), tile,
+                                    interpret_kernels())
     raise ValueError(f"unknown backend {backend!r}; use one of {PROPAGATE_BACKENDS}")

@@ -69,6 +69,8 @@ from ..core.engine import HamletRuntime
 from ..core.optimizer import AlwaysShare, DynamicPolicy, FlopPolicy, NeverShare
 from ..core.pattern import EventType, Kleene, Not, Seq
 from ..core.query import Pred, Query, Workload, agg_avg, agg_sum, count_star
+from ..kernels.ops import PROPAGATE_BACKENDS
+from ..kernels.platform import enable_compile_cache
 from ..obs import PHASES, Observability
 from ..streams.generator import (RIDESHARING_SCHEMA, OverloadStreamConfig,
                                  overload_stream, ridesharing_stream)
@@ -149,10 +151,18 @@ def run_overload(args) -> None:
 
     # calibrate capacity (events/s the unshedded engine sustains) on a prefix
     sample = stream.time_slice(0, min(60, t_end))
-    cal = HamletRuntime(wl, policy=POLICIES[args.policy]())
-    t0 = time.perf_counter()
-    cal.run(sample, t_end=min(60, t_end))
-    capacity = len(sample) / max(time.perf_counter() - t0, 1e-9)
+
+    def calibrate():
+        cal = HamletRuntime(wl, policy=POLICIES[args.policy](),
+                            backend=args.backend)
+        t0 = time.perf_counter()
+        cal.run(sample, t_end=min(60, t_end))
+        return cal, time.perf_counter() - t0
+
+    if args.backend != "np":
+        calibrate()      # compile the device programs outside the timing
+    cal, cal_s = calibrate()
+    capacity = len(sample) / max(cal_s, 1e-9)
 
     pane = cal.pane
     tick_seconds = (len(stream) / t_end) / (args.offered_x * capacity)
@@ -307,6 +317,7 @@ def run_serving(args) -> None:
     fe = ServingFrontend(
         wl, backend="overload",
         overload=OverloadConfig(shed_policy=args.shed_policy, micro_batch=4),
+        np_backend=args.backend,
         groups_per_tenant=args.groups_per_tenant, obs=obs)
     n_sessions = max(1, args.sessions)
     parts, handles = [], []
@@ -372,6 +383,7 @@ def run_listen(args) -> None:
     fe = ServingFrontend(
         wl, backend="overload",
         overload=OverloadConfig(shed_policy=args.shed_policy, micro_batch=4),
+        np_backend=args.backend,
         groups_per_tenant=args.groups_per_tenant, obs=obs)
     srv = ServingServer(fe, host, port, credit_window=args.credit_window)
     host, port = srv.start()
@@ -445,7 +457,10 @@ def main():
     ap.add_argument("--queries", type=int, default=3)
     ap.add_argument("--groups", type=int, default=4)
     ap.add_argument("--policy", choices=list(POLICIES), default="dynamic")
-    ap.add_argument("--backend", default="np")
+    ap.add_argument("--backend", default="np", choices=PROPAGATE_BACKENDS,
+                    help="engine execution backend: np (host) or a device "
+                         "backend (pallas kernels compile on a TPU and "
+                         "interpret elsewhere)")
     ap.add_argument("--overload", action="store_true",
                     help="bounded-latency runtime on an overload scenario")
     ap.add_argument("--serve", action="store_true",
@@ -497,6 +512,7 @@ def main():
     ap.add_argument("--trace-sample", type=int, default=1,
                     help="per-pane track sampling: trace every Nth pane")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.listen:
         run_listen(args)

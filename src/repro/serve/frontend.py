@@ -121,11 +121,11 @@ class _ShardedBackend:
     name = "sharded"
     retracts = False
 
-    def __init__(self, workload, cfg, obs=None):
+    def __init__(self, workload, cfg, backend="np", obs=None):
         # (shard workers own their observability via cfg.obs; the serving
         # facade's registry is merged at collect time, not pushed down)
         from ..shardsvc.service import ShardedHamletService
-        self.svc = ShardedHamletService(workload, cfg)
+        self.svc = ShardedHamletService(workload, cfg, backend=backend)
         self.pane = self.svc.pane
         self.controller = None          # admission lives per shard
         self.accountant = None
@@ -212,7 +212,8 @@ def _make_backend(workload, backend, *, overload=None, shard_cfg=None,
     if backend == "sharded":
         if shard_cfg is None:
             raise ValueError("sharded backend needs a ShardServiceConfig")
-        return _ShardedBackend(workload, shard_cfg, obs=obs)
+        return _ShardedBackend(workload, shard_cfg, backend=np_backend,
+                               obs=obs)
     if backend == "eventtime":
         return _EventTimeBackend(workload, eventtime, policy=policy,
                                  backend=np_backend,
@@ -247,6 +248,8 @@ class ServingFrontend:
     session_admission
                     actuate the backend PID controller's shed ratio per
                     session at submit time (overload backend only)
+    np_backend      the engine's execution backend under any adapter:
+                    "np" (host) | "jax" | "pallas" (device)
     """
 
     def __init__(self, workload, *, backend: str = "overload",

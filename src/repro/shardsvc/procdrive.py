@@ -123,12 +123,14 @@ def _load_chunk(schema, header):
 # worker process main
 # --------------------------------------------------------------------------
 
-def _worker_main(conn, shard_id, workload, cfg, policy, backend,
+def _worker_main(conn, shard_id, workload, cfg, policy,
                  eventtime, skew, lateness_horizon, obs_on) -> None:
     from ..obs.facade import Observability
     from .service import ShardWorker
 
-    w = ShardWorker(shard_id, workload, cfg, policy=policy, backend=backend,
+    # numpy engine only: a chip belongs to one process (see
+    # ShardedHamletService)
+    w = ShardWorker(shard_id, workload, cfg, policy=policy,
                     eventtime=eventtime, skew=skew,
                     lateness_horizon=lateness_horizon,
                     obs=Observability.disabled() if obs_on else None)
@@ -202,7 +204,7 @@ class ProcShardWorker:
     """
 
     def __init__(self, shard_id: int, workload, cfg, *, policy=None,
-                 backend: str = "np", eventtime: bool = False,
+                 eventtime: bool = False,
                  skew: int = 0, lateness_horizon: int | None = None,
                  obs: bool = False, clock=time.perf_counter):
         self.shard_id = int(shard_id)
@@ -222,7 +224,7 @@ class ProcShardWorker:
         self._conn, child = _CTX.Pipe()
         self._proc = _CTX.Process(
             target=_worker_main,
-            args=(child, shard_id, workload, cfg, policy, backend,
+            args=(child, shard_id, workload, cfg, policy,
                   eventtime, skew, lateness_horizon, obs),
             name=f"shard-proc-{shard_id}", daemon=True)
         self._proc.start()

@@ -53,6 +53,7 @@ ratios then depend on per-shard latency, which depends on placement.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field, replace
@@ -115,11 +116,15 @@ class ShardServiceConfig:
                          memory, rendezvous over the command pipe — the
                          mode that can actually exceed 1.0x measured
                          speedup on multi-core hosts.  Rebalance is not
-                         supported in this mode.
+                         supported in this mode, and it needs the numpy
+                         backend: a chip belongs to one process.
 
                        All modes are bitwise identical to the serial drive
                        (workers share no mutable state; the aligner sees
                        the same frontier sequence per cycle).
+
+    On the device backends shard ``i`` launches on ``jax.devices()[i mod
+    count]``: no option, the host's device count decides.
     """
 
     n_shards: int = 2
@@ -174,16 +179,18 @@ class ShardWorker:
     degraded shard — the aligner's laggard-exclusion path and the
     weak-scaling benchmark's slow-shard scenario both use it.  ``cap_t``
     is the rebalance barrier: while set, the pane clock will not advance
-    past it.
+    past it.  ``device`` (device backends) is the jax device this shard's
+    executors launch on; every call that can reach them runs under it.
     """
 
     def __init__(self, shard_id: int, workload: Workload,
                  cfg: OverloadConfig, *, policy=None, backend: str = "np",
                  eventtime: bool = False, skew: int = 0,
                  lateness_horizon: int | None = None, obs=None,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter, device=None):
         self.shard_id = int(shard_id)
         self.obs = obs
+        self.device = device
         self.rt = OverloadRuntime(workload, cfg, policy=policy,
                                   backend=backend, obs=obs)
         self.pane = self.rt.pane
@@ -253,17 +260,26 @@ class ShardWorker:
             lim = min(lim, self.cap_t)
         return lim
 
+    def _placed(self):
+        """Context pinning jax work to this shard's device (thread-local,
+        so thread-pool drives pin each worker independently)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        import jax
+        return jax.default_device(self.device)
+
     def drive(self) -> int:
         """Step every complete pane (bounded by throttle/cap); returns the
         number of panes stepped."""
         c0 = self._clock()
         stepped = 0
         lim = self._step_limit()
-        while self.rt.t_now + self.pane <= lim:
-            if self.throttle is not None and stepped >= self.throttle:
-                break
-            self.rt.step_pane()
-            stepped += 1
+        with self._placed():
+            while self.rt.t_now + self.pane <= lim:
+                if self.throttle is not None and stepped >= self.throttle:
+                    break
+                self.rt.step_pane()
+                stepped += 1
         self.busy_s += self._clock() - c0
         return stepped
 
@@ -290,7 +306,8 @@ class ShardWorker:
 
     def results(self) -> dict:
         c0 = self._clock()
-        out = self.rt.results()
+        with self._placed():
+            out = self.rt.results()
         self.busy_s += self._clock() - c0
         return out
 
@@ -330,6 +347,17 @@ class ShardWorker:
         }
 
 
+def _shard_devices(backend: str, n_shards: int) -> list:
+    """Shard ``i``'s device: ``jax.devices()[i mod count]`` on the device
+    backends (one chip per shard where the host has them, all on the one
+    chip where it has one), ``None`` on numpy."""
+    if backend == "np":
+        return [None] * n_shards
+    import jax
+    devs = jax.devices()
+    return [devs[i % len(devs)] for i in range(n_shards)]
+
+
 class ShardedHamletService:
     """N shard workers behind one router, admission controller and aligner.
 
@@ -349,11 +377,18 @@ class ShardedHamletService:
                                         replicas=cfg.ring_replicas)
         shard_cfg = self._shard_overload_cfg()
         self._mode = cfg.drive_mode
+        if self._mode == "process" and backend != "np":
+            raise ValueError(
+                f"parallel='process' needs backend='np', got {backend!r}: "
+                f"every shard process would open the device itself, and a "
+                f"chip belongs to one process, so the children would fail "
+                f"or hang.  Drive device shards in this process "
+                f"(parallel=False or 'thread').")
         if self._mode == "process":
             from .procdrive import ProcShardWorker
             self.workers = [
                 ProcShardWorker(s, workload, shard_cfg, policy=policy,
-                                backend=backend, eventtime=cfg.eventtime,
+                                eventtime=cfg.eventtime,
                                 skew=cfg.skew,
                                 lateness_horizon=cfg.lateness_horizon,
                                 obs=cfg.obs, clock=clock)
@@ -361,6 +396,7 @@ class ShardedHamletService:
             for w in self.workers:       # spawns overlap; then handshake
                 w.wait_ready()
         else:
+            devices = _shard_devices(backend, cfg.n_shards)
             self.workers = [
                 ShardWorker(s, workload, shard_cfg, policy=policy,
                             backend=backend, eventtime=cfg.eventtime,
@@ -368,7 +404,7 @@ class ShardedHamletService:
                             lateness_horizon=cfg.lateness_horizon,
                             obs=Observability.disabled() if cfg.obs
                             else None,
-                            clock=clock)
+                            clock=clock, device=devices[s])
                 for s in range(cfg.n_shards)]
         self.pane = self.workers[0].pane
         self.admission = GlobalAdmissionController(

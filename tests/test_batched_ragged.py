@@ -80,8 +80,9 @@ def test_dense_batched_empty_and_oversize():
 def test_dense_batched_pallas_interpret():
     rng = np.random.default_rng(3)
     base = (rng.random((2, 65, 2)) * 1e-3).astype(np.float32)  # tile+1 pad
+    # the platform (CPU here) selects the interpreter
     got = np.asarray(ops.propagate_dense_batched(base, backend="pallas",
-                                                 tile=64, interpret=True))
+                                                 tile=64))
     want = np.stack([ref.prefix_propagate_dense_np(base[i].astype(np.float64))
                      for i in range(2)])
     assert np.max(np.abs(got - want) / (1 + np.abs(want))) < 1e-5

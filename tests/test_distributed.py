@@ -108,7 +108,6 @@ def test_compressed_psum_across_pods():
     import jax, numpy as np
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import shard_map
     from repro.distributed.compression import compressed_psum_tree
     mesh = jax.make_mesh((4,), ("pod",))
     def f(g):
@@ -116,8 +115,8 @@ def test_compressed_psum_across_pods():
                                            "pod", 4)
         return synced["w"], err["w"]
     g = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                           out_specs=P("pod"), check_vma=False))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                               out_specs=P("pod"), check_vma=False))
     synced, err = fn(g)
     want = np.asarray(g).reshape(4, 8).mean(axis=0)
     got = np.asarray(synced)[0]
@@ -197,7 +196,10 @@ def test_dp_compressed_train_step():
     from repro.train.optimizer import AdamW
     from repro.distributed.compression import dp_compressed_step_fn
 
-    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+    # Auto axes: the step partitions under GSPMD sharding constraints,
+    # which jax's default Explicit mesh axes refuse
+    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     cfg = reduce_for_smoke(get_config("h2o-danube-1.8b"))
     opt = AdamW(lr=1e-3)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))

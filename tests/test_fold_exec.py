@@ -265,6 +265,19 @@ def test_fold_windows_matches_fold_panes():
     assert fe.window_folds == 3
 
 
+def test_fold_windows_device_backend_pads_and_returns_host_dtype():
+    rng = np.random.default_rng(4)
+    fe = FoldExecutor(backend="jax")
+    # 3 same-shape chains: one launch, batch padded to 4 on the device
+    folds = [(rng.standard_normal(4), [rng.standard_normal((4, 4))
+                                       for _ in range(3)]) for _ in range(3)]
+    got = fe.fold_windows(folds)
+    for (u0, Ms), u in zip(folds, got):
+        assert u.dtype == u0.dtype
+        np.testing.assert_allclose(u, fold_panes(Ms, u0), rtol=1e-12)
+    assert fe.window_folds == 1
+
+
 # -------------------------------------------------- flush-plan caching
 
 

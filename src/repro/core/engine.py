@@ -1705,26 +1705,31 @@ class PaneMicroBatcher:
         pend, self._pending = self._pending, []
         if not pend:
             return pend
-        self._plan_pending(pend)
-        ex = self.executor
         obs = self.obs
+        traced = obs is not None and obs.tracing
+        # with tracing on, the plan, execute and finalize regions are each
+        # one profiler annotation per flush (their ring spans are per-pane)
+        with obs.annotation("plan") if traced else NULL_SPAN:
+            self._plan_pending(pend)
+        ex = self.executor
         sp = (obs.span("flush", args={"panes": len(pend)})
               if obs is not None else NULL_SPAN)
         with sp:
-            t0 = perf_counter()
-            with np.errstate(over="ignore", invalid="ignore"):
-                for p in pend:
-                    p.proc.submit_execute(p.steps, p.stats, 1, p.jobs)
-                ex.flush()
-                for p in pend:
-                    p.proc.submit_execute(p.steps, p.stats, 2, p.jobs)
-                ex.flush()
-            # amortize the fused launch wall time across the micro-batch
-            dt = (perf_counter() - t0) / len(pend)
+            with obs.annotation("execute") if traced else NULL_SPAN:
+                t0 = perf_counter()
+                with np.errstate(over="ignore", invalid="ignore"):
+                    for p in pend:
+                        p.proc.submit_execute(p.steps, p.stats, 1, p.jobs)
+                    ex.flush()
+                    for p in pend:
+                        p.proc.submit_execute(p.steps, p.stats, 2, p.jobs)
+                    ex.flush()
+                # amortize the fused launch wall time across the micro-batch
+                dt = (perf_counter() - t0) / len(pend)
             for p in pend:
                 p.stats.execute_s += dt
             if obs is not None:
-                if obs.tracing:
+                if traced:
                     # the same amortized dt, tiled so pane spans don't overlap
                     for i, p in enumerate(pend):
                         obs.pane_phase("execute", t0 + i * dt, dt,
@@ -1735,7 +1740,7 @@ class PaneMicroBatcher:
             if fe is not None:
                 fsp = (obs.span("fold_flush", args={"panes": len(pend)})
                        if obs is not None else NULL_SPAN)
-                with fsp:
+                with fsp, obs.annotation("finalize") if traced else NULL_SPAN:
                     t1 = perf_counter()
                     fjobs = [fe.submit(p.proc, p.steps, p.jobs, p.stats,
                                        host=p.plan_host) for p in pend]
@@ -1743,15 +1748,15 @@ class PaneMicroBatcher:
                     for p, fj in zip(pend, fjobs):
                         p.M = fj.M
                     dt = (perf_counter() - t1) / len(pend)
-                    for p in pend:
-                        p.stats.finalize_s += dt
-                    if obs is not None:
-                        if obs.tracing:
-                            for i, p in enumerate(pend):
-                                obs.pane_phase("finalize", t1 + i * dt, dt,
-                                               key=p.pane_key)
-                        else:
-                            obs.pane_phase_n("finalize", dt, len(pend))
+                for p in pend:
+                    p.stats.finalize_s += dt
+                if obs is not None:
+                    if traced:
+                        for i, p in enumerate(pend):
+                            obs.pane_phase("finalize", t1 + i * dt, dt,
+                                           key=p.pane_key)
+                    else:
+                        obs.pane_phase_n("finalize", dt, len(pend))
         return pend
 
 
@@ -1929,8 +1934,8 @@ class HamletRuntime:
     def _advance_pane(self, comp, ctx, insts, t0: int, pane_ev: EventBatch,
                       M: np.ndarray, t_end: int, group_key: int,
                       out: dict) -> None:
-        """Phase 4 (fold): advance window instances by one pane and emit
-        closing windows."""
+        """Phase 4 (fold): advance window instances by one pane, then emit
+        the closing windows (one ``emit`` span when tracing)."""
         obs = self.obs
         key = (obs.pane_key(pane_ev)
                if obs is not None and obs.tracing else None)
@@ -1941,7 +1946,6 @@ class HamletRuntime:
             # open new instances whose window starts at this pane
             if t0 % q.slide == 0 and t0 + q.within <= t_end:
                 insts[ci][t0] = _Instance(t0, ctx.layout.fresh_state())
-            needs_minmax = ci in ctx.minmax_queries
             t_fold = perf_counter()
             advance_instances(M[ci], insts[ci])
             d = perf_counter() - t_fold
@@ -1949,19 +1953,25 @@ class HamletRuntime:
             if fold_t0 is None:
                 fold_t0 = t_fold
             fold_dt += d
-            for w0, inst in list(insts[ci].items()):
-                if needs_minmax and len(pane_ev):
-                    inst.events.append(pane_ev)
-                if w0 + q.within == t0 + self.pane:
-                    out[(aqi, group_key, w0)] = self._emit(
-                        ctx, ci, q, inst, group_key)
-                    del insts[ci][w0]
-                    self.stats.windows_emitted += 1
-                    if key is not None:
-                        obs.lifecycle("emit", key,
-                                      args={"w0": w0, "q": aqi})
         if obs is not None and fold_t0 is not None:
             obs.pane_phase("fold", fold_t0, fold_dt, key=key)
+        with (obs.span("emit", cat="engine", annotate=True,
+                       counter="engine.emit_s") if key is not None
+              else NULL_SPAN):
+            for ci, aqi in enumerate(comp):
+                q = self.workload.atomic[aqi]
+                needs_minmax = ci in ctx.minmax_queries
+                for w0, inst in list(insts[ci].items()):
+                    if needs_minmax and len(pane_ev):
+                        inst.events.append(pane_ev)
+                    if w0 + q.within == t0 + self.pane:
+                        out[(aqi, group_key, w0)] = self._emit(
+                            ctx, ci, q, inst, group_key)
+                        del insts[ci][w0]
+                        self.stats.windows_emitted += 1
+                        if key is not None:
+                            obs.lifecycle("emit", key,
+                                          args={"w0": w0, "q": aqi})
 
     def _emit(self, ctx: ComponentContext, ci: int, q: AtomicQuery,
               inst: _Instance, group_key: int) -> dict:

@@ -11,9 +11,22 @@ observability pays nothing.
 disconnected stat silos: it folds ``RunStats``, ``OverloadMetrics``,
 ``EventTimeMetrics`` and the executor counters into one dict next to the
 registry series and the audit summary.
+
+With tracing on, the facade also accounts for the serving pump's time
+outside the engine phases: the ``TIME_COUNTERS`` are registered at
+construction (a window without a compile reads 0, not a missing key) and
+summed by the spans that name the work — ``serve.seal``, ``serve.route``,
+``serve.wait`` (front-end), ``emit`` (window results), ``compile`` (every
+executable built or loaded, from the process-wide listener in
+``kernels/platform.py``) and ``gc`` (collector pauses, from a
+``gc.callbacks`` hook that goes with this facade).
 """
 
 from __future__ import annotations
+
+import gc
+import weakref
+from time import perf_counter
 
 from .audit import SharingAuditLog
 from .metrics import (DEPTH_BUCKETS, LAG_BUCKETS, LATENCY_MS_BUCKETS,
@@ -21,6 +34,41 @@ from .metrics import (DEPTH_BUCKETS, LAG_BUCKETS, LATENCY_MS_BUCKETS,
 from .trace import Tracer
 
 PHASES = ("plan", "execute", "finalize", "fold")
+
+# registered with tracing on: seconds, except the count of programs
+TIME_COUNTERS = ("serve.seal_s", "serve.route_s", "serve.wait_s",
+                 "engine.emit_s", "kernels.compile_s",
+                 "kernels.programs_built", "host.gc_s")
+
+
+def _gc_hook(tracer, counter):
+    """A ``gc.callbacks`` hook recording each collection as a ``gc`` span
+    (a profiler annotation too for full collections, the long pauses)."""
+    state = [0.0, None]
+
+    def hook(phase, info):
+        if phase == "start":
+            ann = (tracer.annotation("gc") if info["generation"] == 2
+                   else None)
+            if ann is not None:
+                ann.__enter__()
+            state[1] = ann
+            state[0] = perf_counter()
+            return
+        dur = perf_counter() - state[0]
+        if state[1] is not None:
+            state[1].__exit__(None, None, None)
+            state[1] = None
+        counter.value += dur
+        tracer.complete("gc", state[0], dur, cat="gc",
+                        args={"gen": info["generation"]})
+
+    return hook
+
+
+def _remove_gc_hook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
 
 
 class Observability:
@@ -41,6 +89,18 @@ class Observability:
         self._counters = {}
         self._gauges = {}
         self._hists = {}
+        if self.tracer.enabled:
+            for name in TIME_COUNTERS:
+                self._counters[name] = self.registry.counter(name)
+            hook = _gc_hook(self.tracer, self._counters["host.gc_s"])
+            gc.callbacks.append(hook)
+            weakref.finalize(self, _remove_gc_hook, hook)
+            try:
+                from ..kernels.platform import add_compile_sink
+            except ImportError:       # no JAX: nothing is compiled
+                pass
+            else:
+                add_compile_sink(self._on_compile)
 
     @classmethod
     def disabled(cls) -> "Observability":
@@ -98,8 +158,26 @@ class Observability:
             self.tracer.instant("plan_cache_hit" if hit
                                 else "plan_cache_miss", key=key, cat="cache")
 
-    def span(self, name, cat="span", args=None):
-        return self.tracer.span(name, cat, args)
+    def span(self, name, cat="span", args=None, annotate=False,
+             counter=None):
+        """Live span (one ``X`` event at exit); ``annotate`` also enters it
+        as a profiler annotation, ``counter`` names a ``TIME_COUNTERS``
+        series that gains its seconds."""
+        return self.tracer.span(name, cat, args, annotate,
+                                self._counters.get(counter))
+
+    def annotation(self, name):
+        """A profiler annotation alone (tracing on), nothing in the ring:
+        for regions whose ring spans are per-pane tiles."""
+        return self.tracer.annotation(name)
+
+    def _on_compile(self, secs: float) -> None:
+        """One executable built or loaded, on the compiling thread: a
+        ``compile`` span ending now, ``secs`` long."""
+        self.tracer.complete("compile", perf_counter() - secs, secs,
+                             cat="compile")
+        self._counters["kernels.compile_s"].value += secs
+        self._counters["kernels.programs_built"].value += 1
 
     # -------------------------------------------------------- metrics hooks
 
@@ -199,5 +277,5 @@ class Observability:
         return out
 
 
-__all__ = ["Observability", "PHASES", "LATENCY_MS_BUCKETS",
+__all__ = ["Observability", "PHASES", "TIME_COUNTERS", "LATENCY_MS_BUCKETS",
            "OCCUPANCY_BUCKETS", "LAG_BUCKETS", "DEPTH_BUCKETS"]

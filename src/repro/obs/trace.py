@@ -5,19 +5,30 @@ ring (oldest events drop first, counted in :attr:`Tracer.dropped`) and
 formatted lazily at export.  The layout follows the Chrome trace event
 format so the output loads directly in Perfetto / ``chrome://tracing``:
 
-* ``tid 0`` is the *engine* track: nested ``B``/``E`` duration spans
-  (micro-batch flush, fold flush, service epochs) plus engine-wide
-  ``X`` phase events that have no pane attribution.
+* ``tid 0`` is the *engine* track: ``X`` complete events for live spans
+  (micro-batch ``flush``, ``fold_flush``, the serving pump's
+  ``serve.*`` spans, ``emit``, ``gc``, ``compile``, service epochs) plus
+  engine-wide ``X`` phase events that have no pane attribution.  A live
+  span is one event, recorded when it ends, so spans of different threads
+  never interleave on a shared stack.
 * ``tid >= 1`` is one track per sampled pane, keyed by
   ``(group, pane_t0)``: ``X`` complete events for the four pipeline
   phases (plan / execute / finalize / fold) and ``i`` instant events for
   lifecycle marks (ingest -> seal -> plan -> execute -> emit ->
-  revise / evict) and plan-cache lookups.
+  revise / evict) and plan-cache lookups.  The key-to-track table keeps
+  the ``max_tracks`` most recently opened panes, so a long-running traced
+  server holds a bounded table.
 
 Timestamps are microseconds relative to tracer construction, taken from
 the *same* ``perf_counter`` readings the engine already uses for
 ``RunStats`` — so per-pane phase spans sum to the ``RunStats`` phase
 totals by construction.
+
+A live span opened with ``annotate=True`` is also entered as a
+``jax.profiler.TraceAnnotation`` on the thread doing the work, so a
+profile taken meanwhile shows it on the host plane, on the same clock as
+the device's programs.  ``jax.profiler`` is imported on the first such
+span only: the module stays importable without JAX.
 
 The export is strict JSONL (one event object per line).  Perfetto loads
 the JSONL directly; for viewers that require the enveloped form, run::
@@ -38,6 +49,14 @@ _PHASES = ("plan", "execute", "finalize", "fold")
 _MISSING = object()
 
 
+def _annotation_cls():
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class _NullSpan:
     """No-op context manager returned when tracing is disabled."""
 
@@ -54,20 +73,38 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_cat", "_args")
+    """A live span: one ``X`` event recorded at exit, its start captured at
+    entry.  ``counter`` (anything with a ``value``) gains the span's
+    seconds; ``dur`` holds them after exit."""
 
-    def __init__(self, tr, name, cat, args):
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_ann", "_counter",
+                 "_t", "dur")
+
+    def __init__(self, tr, name, cat, args, annotate, counter):
         self._tr = tr
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = tr.annotation(name) if annotate else None
+        self._counter = counter
+        self.dur = 0.0
 
     def __enter__(self):
-        self._tr._begin(self._name, self._cat, self._args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t = perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr._end(self._name)
+        t1 = perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.dur = t1 - self._t
+        if self._counter is not None:
+            self._counter.value += self.dur
+        tr = self._tr
+        tr._emit(("X", self._name, self._cat, tr._ts(self._t),
+                  self.dur * 1e6, 0, self._args))
         return False
 
 
@@ -81,7 +118,11 @@ class Tracer:
     unsampled-pane phase events are unaffected by sampling only in the
     sense that unsampled panes simply do not get a track (their events
     are skipped, keeping the ring for the panes that were kept).
+    The pane-key table holds ``max_tracks`` entries: the oldest pane's
+    goes first (a pane seen again after that opens a new track).
     """
+
+    max_tracks = 1 << 12
 
     def __init__(self, capacity: int = 1 << 18, sample: int = 1):
         self.capacity = int(capacity)
@@ -89,12 +130,12 @@ class Tracer:
         self.enabled = self.capacity > 0
         self._events = deque(maxlen=max(1, self.capacity))
         self._t0 = perf_counter()
-        self._stack: list[str] = []
         self._tids: dict = {}
         self._next_tid = 1
         self._panes_seen = 0
         self.dropped = 0
         self._pid = os.getpid()
+        self._ann_cls = _MISSING
 
     # ------------------------------------------------------------- internals
 
@@ -114,6 +155,9 @@ class Tracer:
         if tid is not _MISSING:
             return tid
         self._panes_seen += 1
+        if len(self._tids) >= self.max_tracks:
+            # insertion order: the oldest pane's entry goes first
+            del self._tids[next(iter(self._tids))]
         if (self._panes_seen - 1) % self.sample:
             self._tids[key] = None
             return None
@@ -149,30 +193,36 @@ class Tracer:
                 return
         self._emit(("i", name, cat, self._ts(), 0.0, tid, args))
 
-    def span(self, name, cat="span", args=None):
-        """Nestable ``B``/``E`` duration span on the engine track."""
+    def span(self, name, cat="span", args=None, annotate=False,
+             counter=None):
+        """Live span on the engine track: one ``X`` event at exit.
+        ``annotate`` also enters it as a profiler annotation."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, cat, args, annotate, counter)
 
-    def _begin(self, name, cat, args) -> None:
-        self._stack.append(name)
-        self._emit(("B", name, cat, self._ts(), 0.0, 0, args))
-
-    def _end(self, name) -> None:
-        if self._stack and self._stack[-1] == name:
-            self._stack.pop()
-        self._emit(("E", name, "span", self._ts(), 0.0, 0, None))
+    def annotation(self, name):
+        """A ``jax.profiler.TraceAnnotation`` named ``name`` (a null context
+        when tracing is off or JAX is absent); nothing enters the ring."""
+        cls = self._ann_cls
+        if cls is _MISSING:
+            cls = self._ann_cls = _annotation_cls() if self.enabled else None
+        return NULL_SPAN if cls is None else cls(name)
 
     # --------------------------------------------------------------- export
 
     def __len__(self) -> int:
         return len(self._events)
 
+    def _snapshot(self) -> list:
+        # one C-level copy: no bytecode boundary for another thread, no
+        # allocation for the collector (whose ``gc`` spans enter the ring)
+        return list(self._events)
+
     def events(self) -> list[dict]:
         """Materialise the ring as Chrome trace event dicts."""
         out = []
-        for ph, name, cat, ts, dur, tid, args in self._events:
+        for ph, name, cat, ts, dur, tid, args in self._snapshot():
             ev = {"ph": ph, "name": name, "cat": cat,
                   "ts": round(ts, 3), "pid": self._pid, "tid": tid}
             if ph == "X":
@@ -196,7 +246,7 @@ class Tracer:
     def phase_totals(self) -> dict:
         """Seconds of recorded ``X`` phase-span time, keyed by phase name."""
         tot = {}
-        for ph, name, cat, _ts, dur, _tid, _args in self._events:
+        for ph, name, cat, _ts, dur, _tid, _args in self._snapshot():
             if ph == "X" and cat == "phase":
                 tot[name] = tot.get(name, 0.0) + dur / 1e6
         return tot

@@ -47,7 +47,9 @@ from ..core.engine import (HamletRuntime, PaneMicroBatcher, RunStats,
                            _Instance, advance_instances, combine_results)
 from ..core.events import EventBatch
 from ..core.query import Workload
+from ..kernels.platform import thread_compile_seconds, watch_compiles
 from ..obs.metrics import LATENCY_MS_BUCKETS
+from ..obs.trace import NULL_SPAN
 from .accountant import ErrorAccountant
 from .config import OverloadConfig
 from .controller import LatencyController
@@ -145,14 +147,11 @@ class _GroupDriver:
         micro-batch; returns the pending handles ``apply`` consumes."""
         return [mb.submit(proc, pane_ev, stats) for proc in self.procs]
 
-    def apply(self, pends: list, pane_ev: EventBatch, t0: int, out: dict,
-              stats: RunStats) -> None:
-        """Finalize + fold this group's pane (after the micro-batch drained)."""
+    def fold(self, pends: list, t0: int, stats: RunStats) -> None:
+        """Finalize + fold this group's pane (after the micro-batch
+        drained): open the windows starting here, advance every open one."""
         rt = self.rt
-        pane = rt.pane
         obs = rt.obs
-        key = (self.group_key, t0) if obs is not None and obs.tracing \
-            else None
         fold_t0 = None
         fold_dt = 0.0
         for comp, ctx, pend, per in zip(rt.components, rt.ctxs, pends,
@@ -163,7 +162,6 @@ class _GroupDriver:
                 insts = per[ci]
                 if t0 % q.slide == 0:
                     insts[t0] = _Instance(t0, ctx.layout.fresh_state())
-                needs_minmax = ci in ctx.minmax_queries
                 t_fold = time.perf_counter()
                 advance_instances(M[ci], insts)
                 dt = time.perf_counter() - t_fold
@@ -171,6 +169,24 @@ class _GroupDriver:
                 if fold_t0 is None:
                     fold_t0 = t_fold
                 fold_dt += dt
+        if obs is not None and fold_t0 is not None:
+            key = (self.group_key, t0) if obs.tracing else None
+            obs.pane_phase("fold", fold_t0, fold_dt, key=key)
+
+    def emit(self, pane_ev: EventBatch, t0: int, out: dict,
+             stats: RunStats) -> None:
+        """Build the results of the windows this pane closes and retire
+        their instances (after ``fold``)."""
+        rt = self.rt
+        pane = rt.pane
+        obs = rt.obs
+        key = (self.group_key, t0) if obs is not None and obs.tracing \
+            else None
+        for comp, ctx, per in zip(rt.components, rt.ctxs, self.insts):
+            for ci, aqi in enumerate(comp):
+                q = rt.workload.atomic[aqi]
+                insts = per[ci]
+                needs_minmax = ci in ctx.minmax_queries
                 for w0, inst in list(insts.items()):
                     if needs_minmax and len(pane_ev):
                         inst.events.append(pane_ev)
@@ -182,18 +198,6 @@ class _GroupDriver:
                         if key is not None:
                             obs.lifecycle("emit", key,
                                           args={"w0": w0, "q": aqi})
-        if obs is not None and fold_t0 is not None:
-            obs.pane_phase("fold", fold_t0, fold_dt, key=key)
-
-    def advance(self, pane_ev: EventBatch, t0: int, out: dict,
-                stats: RunStats) -> None:
-        """Single-pane convenience: plan, drain, apply."""
-        mb = PaneMicroBatcher(self.rt.executor, k=1,
-                              fold_exec=self.rt.fold_exec,
-                              obs=self.rt.obs)
-        pends = self.plan(pane_ev, mb, stats)
-        mb.drain()
-        self.apply(pends, pane_ev, t0, out, stats)
 
 
 class OverloadRuntime:
@@ -234,6 +238,7 @@ class OverloadRuntime:
             max_workers=1, thread_name_prefix="flush")
             if config.pipeline_flush else None)
         self._flush_fut = None
+        watch_compiles()      # the controller leaves compile time out
 
     # -- producer side --
 
@@ -326,6 +331,7 @@ class OverloadRuntime:
 
     def _flush_one(self, backlog: list) -> None:
         c0 = self._clock()
+        cs0 = thread_compile_seconds()
         if len(backlog) == 1:
             t0, _n, _keep, _late, kept = backlog[0]
             self._process(kept, t0)
@@ -333,13 +339,17 @@ class OverloadRuntime:
             self._process_batch([(t0, kept)
                                  for t0, _n, _k, _l, kept in backlog])
         # the controller acts on pane-processing time (the directly
-        # controllable quantity), amortized across the fused micro-batch;
-        # end-to-end latency is reported alongside
-        proc_s = (self._clock() - c0) / len(backlog)
+        # controllable quantity), amortized across the fused micro-batch and
+        # less the programs this flush built or loaded: a first use of a
+        # shape is not load; end-to-end latency is reported alongside
+        wall_s = self._clock() - c0
+        proc_s = wall_s / len(backlog)
+        ctl_ms = max(0.0, wall_s - (thread_compile_seconds() - cs0)) \
+            / len(backlog) * 1e3
         obs = self.obs
         for t0, n, keep_n, n_late, kept in backlog:
             lat_ms = self._latency_ms(t0, proc_s)
-            self.controller.update(proc_s * 1e3)
+            self.controller.update(ctl_ms)
             self.metrics.add(PaneMetric(
                 t0=t0, offered=n, admitted=len(kept), shed=n - keep_n,
                 proc_ms=proc_s * 1e3, lat_ms=lat_ms,
@@ -349,8 +359,6 @@ class OverloadRuntime:
                             LATENCY_MS_BUCKETS)
                 obs.observe("overload.pane_shed_lat_ms", lat_ms,
                             LATENCY_MS_BUCKETS)
-                obs.set_gauge("overload.shed_ratio",
-                              self.controller.shed_ratio)
                 if n > keep_n:
                     obs.count("overload.shed_events", n - keep_n)
 
@@ -376,9 +384,17 @@ class OverloadRuntime:
                                                     mb, self.stats))
                 for g, drv in self._drivers.items()])
         mb.drain()
+        obs = self.obs
+        traced = obs is not None and obs.tracing
         for (t0, _kept), per in zip(panes, planned):
-            for drv, pane_ev, pends in per:
-                drv.apply(pends, pane_ev, t0, self._atomic, self.stats)
+            for drv, _pane_ev, pends in per:
+                drv.fold(pends, t0, self.stats)
+            # one span per pane over every group's window results
+            with (obs.span("emit", cat="engine", annotate=True,
+                           counter="engine.emit_s") if traced
+                  else NULL_SPAN):
+                for drv, pane_ev, _pends in per:
+                    drv.emit(pane_ev, t0, self._atomic, self.stats)
 
     def _latency_ms(self, t0: int, proc_s: float) -> float:
         ts = self.config.tick_seconds

@@ -61,6 +61,7 @@ from ..core.engine import vals_equal
 from ..core.events import EventBatch
 from ..obs.metrics import (SERVE_LATENCY_MS_BUCKETS, Histogram,
                            serve_latency_series)
+from ..obs.trace import NULL_SPAN
 from .scheduler import _SEQ_SPAN, ContinuousBatcher, SessionAdmission
 from .session import Delivery, SessionHandle, _SessionState
 
@@ -260,6 +261,8 @@ class ServingFrontend:
                  clock: Callable[[], float] = time.perf_counter):
         self.workload = workload
         self.obs = obs
+        self._tracing = obs is not None and obs.tracing
+        self._wait = None          # the pump loop's open serve.wait span
         self._clock = clock
         self._backend = _make_backend(
             workload, backend, overload=overload, shard_cfg=shard_cfg,
@@ -300,7 +303,6 @@ class ServingFrontend:
         self.deliveries = 0
         self.submitted = 0
         self.pump_cycles = 0
-        self.pump_wall_s = 0.0
         self.staging_hwm = 0          # high-water of staged-not-yet-sealed
 
         self._pump_thread: threading.Thread | None = None
@@ -368,8 +370,6 @@ class ServingFrontend:
             self.obs.count("serve.submitted", n)
             self.obs.set_gauge("serve.staging_events", staged)
             self.obs.set_gauge("serve.staging_hwm", self.staging_hwm)
-            if shed:
-                self.obs.count("serve.session_shed", shed)
         return n
 
     def advance(self, sid: int, t: int) -> None:
@@ -412,44 +412,79 @@ class ServingFrontend:
         with self._pump_lock:
             return self._pump_locked()
 
-    def _pump_locked(self, upto: int | None = None) -> int:
-        c0 = self._clock()
+    def _pump_locked(self, upto: int | None = None,
+                     loop: bool = False) -> int:
+        """One pump cycle.  With tracing on it is named in spans: a cycle
+        that seals or routes anything records ``serve.seal``,
+        ``serve.flush`` (the backend's ingest) and ``serve.route``; on the
+        pump loop (``loop``), the time between two such cycles, idle cycles
+        and sleeps included, is one ``serve.wait`` span."""
+        tr = self._tracing
+        obs = self.obs
+        chunk = records = None
         with self._lock:
-            chunk, boundary = self._batcher.seal(upto)
+            if self._batcher.ready(upto):
+                if loop:
+                    self._end_wait()
+                with (obs.span("serve.seal", cat="serve", annotate=True,
+                               counter="serve.seal_s") if tr
+                      else NULL_SPAN):
+                    chunk, boundary = self._batcher.seal(upto)
         n = 0
         if chunk is not None:
             self._log_seal(boundary)
-            if self.obs is not None:
-                with self.obs.span("serve.flush", cat="serve",
-                                   args={"events": len(chunk),
-                                         "boundary": boundary}):
-                    records = self._backend.ingest(chunk, boundary)
-            else:
+            with (obs.span("serve.flush", cat="serve", annotate=True,
+                           args={"events": len(chunk),
+                                 "boundary": boundary}) if tr
+                  else NULL_SPAN):
                 records = self._backend.ingest(chunk, boundary)
             n = len(chunk)
             self._dirty = True
-            if records:
-                self._route_records(records)
         # diff-based backends emit only on flush boundaries: collect when
         # the micro-batch has actually flushed, never force a partial one
-        if (not self._backend.retracts and self._dirty
-                and not self._backend.pending_flush()):
-            self._route_diff()
-            self._dirty = False
+        diff = (not self._backend.retracts and self._dirty
+                and not self._backend.pending_flush())
+        if records or diff:
+            if loop:
+                self._end_wait()
+            with (obs.span("serve.route", cat="serve", annotate=True,
+                           counter="serve.route_s") if tr else NULL_SPAN):
+                if records:
+                    self._route_records(records)
+                if diff:
+                    self._route_diff()
+                    self._dirty = False
         self.pump_cycles += 1
-        self.pump_wall_s += self._clock() - c0
+        if loop and (chunk is not None or records or diff):
+            self._begin_wait()
         return n
+
+    def _begin_wait(self) -> None:
+        self._wait = self.obs.span("serve.wait", cat="serve", annotate=True,
+                                   counter="serve.wait_s")
+        self._wait.__enter__()
+
+    def _end_wait(self) -> None:
+        if self._wait is not None:
+            self._wait.__exit__(None, None, None)
+            self._wait = None
 
     def start(self, interval_s: float = 0.002) -> None:
         """Run the pump on a background thread until ``stop``/``drain``."""
         if self._pump_thread is not None:
             return
         self._stop.clear()
+        traced = self._tracing
 
         def loop():
+            if traced:
+                self._begin_wait()
             while not self._stop.is_set():
-                self.pump()
+                with self._pump_lock:
+                    self._pump_locked(loop=traced)
                 self._stop.wait(interval_s)
+            if traced:
+                self._end_wait()
 
         self._pump_thread = threading.Thread(target=loop, name="serve-pump")
         self._pump_thread.start()
@@ -603,7 +638,6 @@ class ServingFrontend:
             "session_shed": (self._admission.shed_total
                              if self._admission else 0),
             "pump_cycles": self.pump_cycles,
-            "pump_wall_s": self.pump_wall_s,
         }
 
     def collect(self) -> dict:

@@ -109,6 +109,15 @@ class ContinuousBatcher:
         # it seals by its own computed boundary, not through here.
         return self.sealed_to
 
+    def ready(self, upto: int | None = None) -> bool:
+        """Whether :meth:`seal` would hand out a chunk now: the pane-aligned
+        boundary advances, or a staged event lies below it.  Cheaper than
+        a seal that finds nothing (no merge of the staged batches)."""
+        wm = self.watermark() if upto is None else int(upto)
+        boundary = max((wm // self.pane) * self.pane, self.sealed_to)
+        return boundary > self.sealed_to or any(
+            int(b.time.min()) < boundary for b in self._staged)
+
     def seal(self, upto: int | None = None) -> tuple[EventBatch | None, int]:
         """Merge and hand out every staged event below the pane-aligned
         watermark (or the explicit ``upto``); returns ``(chunk, boundary)``

@@ -1012,7 +1012,7 @@ class PaneProcessor:
                 obs.cache_event(False, pkey)
         before = cache.snapshot_stats(stats) if cache is not None else None
 
-        steps = self._build_steps(plan_bursts, stats)
+        steps, stamped = self._build_steps(plan_bursts, stats)
 
         if cache is not None:
             delta = cache.stat_delta(before, stats)
@@ -1025,34 +1025,89 @@ class PaneProcessor:
             zero_copy = (not ctx.sum_unit_cols and all(
                 isinstance(s, _NegStep) or len(s.div_rows) == 0
                 for s in steps))
-            plan = PanePlan(steps=[self._strip(s) for s in steps],
-                            stat_delta=delta, zero_copy=zero_copy)
+            tmpl: list = []
+            for i, s in enumerate(steps):
+                t = stamped.get(i)
+                tmpl.append(self._strip(s) if t is None
+                            else self._stamp(tmpl[t], s.g, s.rows))
+            plan = PanePlan(steps=tmpl, stat_delta=delta,
+                            zero_copy=zero_copy)
             cache.put(key, plan)
             self._last_host = plan
         return steps
 
-    def _build_steps(self, plan_bursts: list, stats: RunStats) -> list:
+    def _build_steps(self, plan_bursts: list,
+                     stats: RunStats) -> tuple[list, dict]:
         """Construct the structural step list (the cacheable part of phase 1:
         group plans with divergence layout, adjacency, z columns, and
-        count-round injection rows)."""
+        count-round injection rows).
+
+        Within a burst, single-query graphlets without an edge mask whose
+        Kleene flag, start flag and match row agree plan to the same
+        structure: the first is built by :meth:`_plan_group`, the others are
+        stamped from it (own ``g``, ``rows`` and ``mvec``; every structural
+        array shared — plans are immutable).  Returns ``(steps, stamped)``
+        with ``stamped`` mapping a stamped step's index to its template's."""
+        ctx = self.ctx
         steps: list = []
+        stamped: dict[int, int] = {}
+        n_neg = 0
         for bi, (hits, burst) in enumerate(plan_bursts):
             if hits:
                 steps.append(_NegStep(hits))
+                n_neg += 1
             if burst is None:
                 continue
             tid, el, attrs, b, q_pos, mvec, epm, groups = burst
             qpos_index = {qi: i for i, qi in enumerate(q_pos)}
+            # structure class -> index of its built step (None: no step)
+            classes: dict[tuple, int | None] = {}
+            kflag = ctx.kleene_flag[:, el].tolist()
+            sflag = ctx.start_flag[:, el].tolist()
             for g in groups:
                 if len(g) >= 2:
                     stats.shared_bursts += 1
                     stats.shared_graphlets += 1
                 stats.graphlets += 1
                 rows = [qpos_index[qi] for qi in g]
+                ck = None
+                if len(g) == 1 and epm[rows[0]] is None:
+                    qi, row = g[0], rows[0]
+                    ck = (kflag[qi], sflag[qi], mvec[row].tobytes())
+                    if ck in classes:
+                        t = classes[ck]
+                        if t is not None:
+                            stamped[len(steps)] = t
+                            steps.append(self._stamp(steps[t], g, rows,
+                                                     mvec[row:row + 1]))
+                        continue
+                n0 = len(steps)
                 self._plan_group(g, el, tid, attrs, b, mvec[rows],
                                  [epm[i] for i in rows], steps, stats, bi,
                                  rows)
-        return steps
+                if ck is not None:
+                    classes[ck] = n0 if len(steps) > n0 else None
+        obs = self.obs
+        if obs is not None:
+            obs.count("engine.plan.graphlets", len(steps) - n_neg)
+            obs.count("engine.plan.graphlets_stamped", len(stamped))
+        return steps, stamped
+
+    @staticmethod
+    def _stamp(st: _GroupPlan, g: list, rows: list,
+               mvec: np.ndarray | None = None) -> _GroupPlan:
+        """A copy of the single-query step ``st`` for another member ``g``
+        (rows ``rows`` of the burst's match stack) sharing every structural
+        array of ``st``.  ``mvec``, the member's own match row, is given for
+        a live step; a cache template carries no per-pane data."""
+        gp = object.__new__(_GroupPlan)
+        d = st.__dict__.copy()
+        d["g"] = g
+        d["rows"] = rows
+        if mvec is not None:
+            d["mvec"] = mvec
+        gp.__dict__ = d
+        return gp
 
     @staticmethod
     def _strip(step):

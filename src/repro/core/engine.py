@@ -1091,6 +1091,7 @@ class PaneProcessor:
         if obs is not None:
             obs.count("engine.plan.graphlets", len(steps) - n_neg)
             obs.count("engine.plan.graphlets_stamped", len(stamped))
+            obs.count("engine.plan.neg_steps", n_neg)
         return steps, stamped
 
     @staticmethod
@@ -1452,10 +1453,12 @@ class PaneProcessor:
         list, parallel to ``steps`` (plans stay immutable: see _GroupPlan).
         """
         ex = self.executor
+        n = 0
         if round_ == 1:
             for i, p in enumerate(steps):
                 if not isinstance(p, _GroupPlan):
                     continue
+                n += 1
                 base = self._count_base(p)
                 if p.trivial:
                     # non-Kleene graphlet: the in-burst adjacency is all
@@ -1471,6 +1474,7 @@ class PaneProcessor:
                 if not isinstance(p, _GroupPlan):
                     continue
                 cjob, sjobs = jobs[i]
+                n += len(p.sum_units)
                 for ui, vals in p.sum_units:
                     base = self._sum_base(p, ui, vals, cjob.result)
                     if p.trivial:
@@ -1479,6 +1483,9 @@ class PaneProcessor:
                         sjobs[ui] = ex.submit(base,
                                               None if p.dense else p.em)
                     stats.propagate_cells += p.b * p.B_local
+        if self.obs is not None:
+            self.obs.count("batch_exec.jobs.count" if round_ == 1
+                           else "batch_exec.jobs.sum", n)
 
     # -- phase 2 helpers: injection rows for the batched launches --
 
@@ -1773,12 +1780,22 @@ class PaneMicroBatcher:
             with obs.annotation("execute") if traced else NULL_SPAN:
                 t0 = perf_counter()
                 with np.errstate(over="ignore", invalid="ignore"):
-                    for p in pend:
-                        p.proc.submit_execute(p.steps, p.stats, 1, p.jobs)
-                    ex.flush()
-                    for p in pend:
-                        p.proc.submit_execute(p.steps, p.stats, 2, p.jobs)
-                    ex.flush()
+                    # one span per propagate round (tracing on only): the
+                    # sum round's injection rows read the synced counts
+                    with (obs.span("execute.count",
+                                   counter="batch_exec.count_round_s")
+                          if traced else NULL_SPAN):
+                        for p in pend:
+                            p.proc.submit_execute(p.steps, p.stats, 1,
+                                                  p.jobs)
+                        ex.flush()
+                    with (obs.span("execute.sum",
+                                   counter="batch_exec.sum_round_s")
+                          if traced else NULL_SPAN):
+                        for p in pend:
+                            p.proc.submit_execute(p.steps, p.stats, 2,
+                                                  p.jobs)
+                        ex.flush()
                 # amortize the fused launch wall time across the micro-batch
                 dt = (perf_counter() - t0) / len(pend)
             for p in pend:

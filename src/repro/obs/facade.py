@@ -16,9 +16,10 @@ With tracing on, the facade also accounts for the serving pump's time
 outside the engine phases: the ``TIME_COUNTERS`` are registered at
 construction (a window without a compile reads 0, not a missing key) and
 summed by the spans that name the work — ``serve.seal``, ``serve.route``,
-``serve.wait`` (front-end), ``emit`` (window results), ``compile`` (every
-executable built or loaded, from the process-wide listener in
-``kernels/platform.py``) and ``gc`` (collector pauses, from a
+``serve.wait`` (front-end), ``emit`` (window results), ``execute.count``
+and ``execute.sum`` (a micro-batch flush's two propagate rounds),
+``compile`` (every executable built or loaded, from the process-wide
+listener in ``kernels/platform.py``) and ``gc`` (collector pauses, from a
 ``gc.callbacks`` hook that goes with this facade).
 """
 
@@ -37,7 +38,8 @@ PHASES = ("plan", "execute", "finalize", "fold")
 
 # registered with tracing on: seconds, except the count of programs
 TIME_COUNTERS = ("serve.seal_s", "serve.route_s", "serve.wait_s",
-                 "engine.emit_s", "kernels.compile_s",
+                 "engine.emit_s", "batch_exec.count_round_s",
+                 "batch_exec.sum_round_s", "kernels.compile_s",
                  "kernels.programs_built", "host.gc_s")
 
 

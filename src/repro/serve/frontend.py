@@ -513,11 +513,14 @@ class ServingFrontend:
             self._pump_locked(upto=t_end)
             self._log_seal(t_end)
             records = self._backend.finish(t_end)
-            if records:
-                self._route_records(records)
-            if not self._backend.retracts:
-                self._route_diff()
-                self._dirty = False
+            with (self.obs.span("serve.route", cat="serve", annotate=True,
+                                counter="serve.route_s") if self._tracing
+                  else NULL_SPAN):
+                if records:
+                    self._route_records(records)
+                if not self._backend.retracts:
+                    self._route_diff()
+                    self._dirty = False
             res = self._backend.results()
             with self._lock:
                 for h in self._sessions.values():

@@ -12,7 +12,8 @@ Mechanics:
   eventual merge order is a pure function of the submissions, never of
   their interleaving);
 * each open session carries a **frontier** — the promise that its future
-  events have ``time >= frontier`` (advanced by its own submissions, by
+  events have ``time >= frontier`` (advanced by its own submissions to
+  their last tick, which a later submission may still extend, by
   ``advance_to`` heartbeats, or released by ``close``);
 * the **serving watermark** is ``min(session frontiers) - skew``; every
   pane ending at or below it is complete *regardless of which session the
@@ -75,8 +76,9 @@ class ContinuousBatcher:
             t_max = int(batch.time[-1])
             self._max_staged = max(self._max_staged, t_max)
             cur = self._frontiers.get(sid)
+            # its next submission may hold more events of tick t_max
             self._frontiers[sid] = max(cur if cur is not None else 0,
-                                       t_max + 1)
+                                       t_max)
         elif sid not in self._frontiers:
             self._frontiers[sid] = 0
 

@@ -145,6 +145,25 @@ def test_traced_serve_names_the_pump_time():
     assert "chipbench.window" not in {e[1] for e in evs}
 
 
+def test_drain_routes_the_tail_inside_a_route_span():
+    """What ``drain`` delivers is routed inside ``serve.route`` too, and
+    counted in ``serve.route_s``: a run whose pump never routed (all of it
+    staged before the drain) still names its route time."""
+    wl, stream = _ride()
+    obs = Observability(audit=False)
+    fe = ServingFrontend(wl, backend="overload", obs=obs,
+                         overload=OverloadConfig(shed_policy="none",
+                                                 micro_batch=4))
+    s = fe.open_session(groups="all")
+    s.submit(stream)
+    s.close()
+    assert fe.drain()
+    evs = _x(obs, "serve.route")
+    assert evs
+    assert sum(e[4] for e in evs) / 1e6 == pytest.approx(
+        obs.registry.collect()["serve.route_s"], rel=1e-9, abs=1e-12)
+
+
 def test_emit_spans_one_per_pane():
     wl, stream = _ride()
     obs = Observability(audit=False)

@@ -245,7 +245,7 @@ def test_continuous_batcher_watermark_and_seal():
     cb.advance(1, 18)
     chunk, boundary = cb.seal()
     assert boundary == 10 and len(chunk) == 10
-    cb.release(1)           # closed: only session 0's frontier (25) holds
+    cb.release(1)           # closed: only session 0's frontier (24) holds
     chunk, boundary = cb.seal()
     assert boundary == 20 and len(chunk) == 10
     assert cb.sealed_events == 20 and len(cb) == 5
@@ -283,6 +283,31 @@ def test_session_opening_after_all_others_closed_keeps_its_stream():
     _assert_same(res, ref, "late-opening session")
     got_b = [d for d in sB.poll() if d.kind != "retract"]
     assert got_b and all(d.group < gpt for d in got_b)
+
+
+def test_submission_split_inside_a_tick_keeps_its_tail():
+    """A submission promises no event earlier than its last tick, not
+    past it: the next one may carry more events of that tick.  A pump
+    between the two, with the other sessions ahead, must not seal that
+    tick's pane and drop the tail as late."""
+    wl, stream = _dataset("taxi")
+    cfg = OverloadConfig(shed_policy="none", micro_batch=4)
+    fe = ServingFrontend(wl, backend="overload", overload=cfg,
+                         groups_per_tenant=2)
+    parts = _by_tenant(stream, 3)
+    sessions = [fe.open_session(tenant=t) for t in range(3)]
+    p0, t = parts[0], parts[0].time
+    cut = next(i for i in range(1, len(t))
+               if t[i] == t[i - 1] and (t[i] + 1) % fe.pane == 0)
+    for s, p in zip(sessions[1:], parts[1:]):
+        s.submit(p)
+    sessions[0].submit(p0.select(np.arange(cut)))
+    fe.pump()
+    sessions[0].submit(p0.select(np.arange(cut, len(p0))))
+    for s in sessions:
+        s.close()
+    _assert_same(fe.drain(), OverloadRuntime(wl, cfg).run(stream),
+                 "a tick split across submissions")
 
 
 def test_sessions_fill_shared_microbatches():

@@ -34,10 +34,32 @@ execute phase preserves it (``kernels/ref.py``): every stacked operation is
 the *stacked twin* of the per-group numpy call — batched ``np.matmul`` whose
 slices run the identical per-slice GEMM, stacked axis-1 column sums whose
 slices run the identical axis-0 reduction, boolean masks, and ``np.where``
-selects of exactly-zero lanes.  The event-snapshot fill loop (rank-1 ``P``
-updates per divergent row) advances all bucket members one divergent row at
-a time; members are independent, so interleaving them is a no-op, and the
-per-row arithmetic keeps the sequential operand order.
+selects of exactly-zero lanes.  The d == 0 column sums of a flush are one
+stacked ``sum`` per burst length, each slice adding its rows in order as the
+per-group ``coef.sum(axis=0)`` does.  The event-snapshot fill loop (rank-1
+``P`` updates per divergent row) advances all bucket members one divergent
+row at a time; members are independent, so interleaving them is a no-op, and
+the per-row arithmetic keeps the sequential operand order.
+
+Stacked-unit fills.  A divergent row fills its count column and then one
+column per SUM/AVG unit; a fill adds ``col * f`` to the ``P`` of every unit
+whose coefficients have that column non-zero.  Once per bucket the executor
+reads off the coefficients which units each fill column feeds (one
+vectorised ``any``).  Where every sum column feeds only its own unit — the
+shape the sum rounds produce — a sum unit's snapshot read cannot see another
+sum unit's fill of the same row, so each row runs as one stacked step: the
+count read and fill, then every sum unit's read (one batched matvec, with
+the ``v * f_c`` injection), and fill.  ``P`` is then held as ``[U, b, nm,
+C]``: a group's members share its coefficients, so a fill is one outer
+product per (unit, group) over the group's contiguous members.  Every
+element still gets the same products added in the same order, and a
+(unit, group) block whose column is all zero is left untouched, so ``0 *
+inf`` lands exactly where the sequential replay puts it.  COUNT alone, or a
+sum column that feeds another unit, keeps the column-by-column loop.  The
+executor counts divergent buckets by form (``fill_stacked``,
+``fill_sequential``; the registry's ``fold_exec.fill.stacked`` and
+``fold_exec.fill.sequential``, always on), and with tracing on records one
+``finalize.fill`` span per divergent bucket into ``fold_exec.fill_s``.
 
 Cache tiers (warm panes skip fold planning entirely)
 ----------------------------------------------------
@@ -74,6 +96,7 @@ import numpy as np
 from ..kernels import ops
 from ..kernels.platform import device_dtype
 from ..obs.metrics import OCCUPANCY_BUCKETS
+from ..obs.trace import NULL_SPAN
 from .batch_exec import _next_pow2
 
 __all__ = ["FoldExecutor", "FoldJob", "FoldSchedule", "build_fold_schedule"]
@@ -333,7 +356,7 @@ class _FlushPlan:
     of the whole flush; rows of trivial graphlets are pre-summed at build
     time (their count coefficients are the plan-cached injection rows), the
     rest are rewritten each flush by ``s_fill`` — one stacked column sum per
-    distinct burst length across *all* rounds.
+    distinct burst length across *all* rounds and units.
 
     A *scannable* plan (every round: no negation steps, exactly one d == 0
     bucket) additionally carries a compiled execution form: ``scan`` (device
@@ -342,14 +365,10 @@ class _FlushPlan:
 
     rounds: list           # [_Round]
     s_flat: np.ndarray | None
-    s_fill: list           # [(global ordinals, [(state row, step idx)])]
+    s_fill: list           # [(s_flat rows, [(state row, step idx, unit)])]
     scan: _ScanProgram | None = None
     fast: list | None = None      # [(merged bucket, S_all row offset)]
     fast_cat: np.ndarray | None = None   # concatenated gof_g of all rounds
-    # fused form of ``s_fill``: (segment refs [(row, step, unit)], segment
-    # start offsets, flat ordinals) — one concatenate + one reduceat per
-    # flush instead of one stack + sum per distinct burst length
-    s_fill_cat: tuple | None = None
 
 
 class FoldExecutor:
@@ -377,6 +396,10 @@ class FoldExecutor:
         self.plan_hits = 0
         self.plan_misses = 0
         self.plan_evictions = 0
+        # divergent buckets by event-snapshot fill form (see
+        # ``_fill_snapshots``): all units per row, or column by column
+        self.fill_stacked = 0
+        self.fill_sequential = 0
 
     def __len__(self) -> int:
         return len(self._pending)
@@ -428,20 +451,16 @@ class FoldExecutor:
             ctx = ctx_of[cid]
             fp = self._plan(cid, cjobs)
             # flush-global dynamic S fills: one stacked column sum per
-            # distinct burst length across every round of the flush —
-            # bitwise equal per slice to the per-group ``coef.sum(axis=0)``
+            # distinct burst length across every round and unit of the
+            # flush — each slice adds its rows in order, bitwise the
+            # per-group ``coef.sum(axis=0)`` (a segmented ``reduceat`` is
+            # not: it reassociates segments of three rows or more)
             S_flat = fp.s_flat
-            if fp.s_fill_cat is not None:
-                # one gather + one segmented column sum for every dynamic
-                # fill of the flush; each reduceat segment adds the same
-                # rows in the same order as the per-group ``sum(axis=1)``
-                refs, starts, ords = fp.s_fill_cat
-                jb = cjobs
-                cat = np.concatenate(
-                    [jb[row].jobs[si][0].result if u == 0
-                     else jb[row].jobs[si][1][u].result
-                     for row, si, u in refs])
-                S_flat[ords] = np.add.reduceat(cat, starts, axis=0)
+            for ords, refs in fp.s_fill:
+                S_flat[ords] = np.stack(
+                    [cjobs[row].jobs[si][0].result if u == 0
+                     else cjobs[row].jobs[si][1][u].result
+                     for row, si, u in refs]).sum(axis=1)
             if fp.scan is not None:
                 # device-resident warm path: the whole fold chain is one
                 # lax.scan launch and one host sync, independent of depth
@@ -520,24 +539,15 @@ class FoldExecutor:
             for go, row in enumerate(s_rows):
                 if row is not None:
                     s_flat[go * n_used:(go + 1) * n_used] = row
-            # group the dynamic fills by (burst length, unit): each becomes
-            # one flush-wide stacked column sum
-            fill_refs: list = []
-            fill_ords: list = []
-            fill_lens: list = []
-            for b, entries in s_dyn.items():
+            # group the dynamic fills by burst length: each becomes one
+            # flush-wide stacked column sum over every unit
+            pos = np.arange(n_used)
+            for entries in s_dyn.values():
                 ords = np.asarray([o for o, _ in entries], dtype=int)
-                refs = [r for _, r in entries]
-                for pos, u in enumerate(used):
-                    s_fill.append((ords * n_used + pos, refs, u))
-                    fill_refs.extend((row, si, u) for row, si in refs)
-                    fill_ords.append(ords * n_used + pos)
-                    fill_lens.extend([b] * len(refs))
+                s_fill.append(((ords[:, None] * n_used + pos).ravel(),
+                               [(row, si, u) for _, (row, si) in entries
+                                for u in used]))
         fp = _FlushPlan(rounds=rounds, s_flat=s_flat, s_fill=s_fill)
-        if s_fill:
-            starts = np.zeros(len(fill_lens), dtype=np.intp)
-            np.cumsum(fill_lens[:-1], out=starts[1:])
-            fp.s_fill_cat = (fill_refs, starts, np.concatenate(fill_ords))
         if self._scannable(ctx, fp):
             if self.backend != "np":
                 fp.scan = self._build_scan(ctx, len(cjobs), fp)
@@ -796,31 +806,24 @@ class FoldExecutor:
         """d > 0: event-level snapshot fills — exact burst length per
         bucket, per-event arrays stacked across members."""
         self.launches += 1
-        if self.obs is not None:
-            self.obs.observe("fold_exec.bucket_occupancy", len(mb.flat_gq),
-                             OCCUPANCY_BUCKETS)
+        obs = self.obs
+        if obs is not None:
+            obs.observe("fold_exec.bucket_occupancy", len(mb.flat_gq),
+                        OCCUPANCY_BUCKETS)
         nu, t, C = st.nu, st.t, st.C
         used, n_used = mb.used, len(mb.used)
 
-        # fetch per-group coefficients and seed S with the per-group column
-        # sums, in group order
-        coef_stacks: dict[int, list] = {u: [] for u in used}
+        # fetch per-group coefficients (one list per group, in ``used``
+        # order) and seed S with the per-group column sums, in group order
+        coef_g: list[list] = []
         S_rows: list[np.ndarray] = []
         steps_g = []
         for row, si in mb.group_refs:
             cjob, sjobs = cjobs[row].jobs[si]
             steps_g.append(cjobs[row].steps[si])
-            coefs = {0: cjob.result}
-            for ui in used[1:]:
-                coefs[ui] = sjobs[ui].result
-            for u in used:
-                coef_stacks[u].append(coefs[u])
-            if n_used > 1:
-                S_rows.append(np.stack(
-                    [coefs[0].sum(axis=0)]
-                    + [coefs[ui].sum(axis=0) for ui in used[1:]]))
-            else:
-                S_rows.append(coefs[0].sum(axis=0)[None])
+            coefs = [cjob.result] + [sjobs[ui].result for ui in used[1:]]
+            coef_g.append(coefs)
+            S_rows.append(np.stack([c.sum(axis=0) for c in coefs]))
 
         zm = st.Z2.take(mb.flat_gq, axis=0)
         nm = len(mb.flat_gq)
@@ -832,9 +835,19 @@ class FoldExecutor:
                 mb.ptm[:, None, None, :],
                 zm[:, 1:1 + nu * t].reshape(nm, nu, t, C))[:, :, 0, :]
 
-        self._fill_snapshots(st.ctx, W, gate_m, used, mb.b, mb.d,
-                             div_g=mb.div_g, gof=mb.gof, steps_g=steps_g,
-                             coef_stacks=coef_stacks, start_m=mb.start)
+        with (obs.span("finalize.fill", counter="fold_exec.fill_s")
+              if obs is not None and obs.tracing else NULL_SPAN):
+            stacked = self._fill_snapshots(
+                st.ctx, W, gate_m, used, mb.b, mb.d, div_g=mb.div_g,
+                gof=mb.gof, steps_g=steps_g, coef_g=coef_g,
+                start_m=mb.start)
+        if stacked:
+            self.fill_stacked += 1
+        else:
+            self.fill_sequential += 1
+        if obs is not None:
+            obs.count("fold_exec.fill.stacked" if stacked
+                      else "fold_exec.fill.sequential")
 
         S_m = np.stack(S_rows)[mb.gof]
         upd = np.matmul(S_m, W).reshape(nm * n_used, C)
@@ -843,30 +856,46 @@ class FoldExecutor:
             rows, em = mb.flat_er
             st.Zf[rows] += upd if em is None else upd[em]
 
-    def _fill_snapshots(self, ctx, W, gate_m, used, b, d, *, div_g, gof,
-                        steps_g, coef_stacks, start_m) -> None:
+    @staticmethod
+    def _fill_snapshots(ctx, W, gate_m, used, b, d, *, div_g, gof, steps_g,
+                        coef_g, start_m) -> bool:
         """Stacked twin of the event-snapshot fill loop: all bucket members
-        advance one divergent row per iteration; ``P[u]`` carries the rank-1
-        updates exactly as the sequential replay does."""
-        nu, C = ctx.nu, ctx.layout.size
-        nm = len(gof)
-        mv_m = np.stack([s.mvec[i] for s, i in self._members(steps_g, gof)])
-        adj = np.repeat(np.tril(np.ones((b, b), dtype=bool), k=-1)[None],
-                        nm, axis=0)
-        for m, (s, i) in enumerate(self._members(steps_g, gof)):
-            e = s.epm[i]
-            if e is not None:
-                adj[m] &= e
-        adj &= mv_m[:, None, :]
+        advance one divergent row per iteration; ``P[k]`` (unit
+        ``used[k]``) carries the rank-1 updates exactly as the sequential
+        replay does.
 
-        coef_m = {u: np.stack(coef_stacks[u])[gof] for u in used}
-        P = {u: np.matmul(coef_m[u], W) for u in used}
+        When no sum column of any divergent row feeds another unit's ``P``
+        (read off the coefficients), the sum units' snapshot reads are
+        independent once the row's count column is filled, and each row
+        advances every unit in one stacked step (:meth:`_fill_stacked`);
+        otherwise, and for COUNT alone, the fills run one column and one
+        unit at a time.  Returns True for the stacked form."""
+        nu = ctx.nu
+        nm, U = len(gof), len(used)
+        n_sum = U - 1
+        ar = np.arange(nm)
+        # member stacks: a group's members are contiguous in ``gof``, in
+        # the row order of the group's ``mvec`` and ``epm``
+        mv_m = np.concatenate([s.mvec for s in steps_g])          # [nm, b]
+        adj = np.tril(np.ones((b, b), dtype=bool), k=-1) & mv_m[:, None, :]
+        epm = [e for s in steps_g for e in s.epm]
+        have = [m for m, e in enumerate(epm) if e is not None]
+        if have:
+            adj[have] &= np.stack([epm[m] for m in have])
+        # per divergent row: the members' adjacency rows and match flags
+        i_m = div_g[gof]                                           # [nm, d]
+        ROWF = adj[ar[:, None], i_m].transpose(1, 0, 2).astype(float)
+        MFL = mv_m[ar[:, None], i_m].T                             # [d, nm]
+        base_c = start_m[:, None] * gate_m + W[:, 1]
+        zc0 = 1 + nu + np.arange(d) * nu
+        # [U, ng, b, B_local]: the members of a group share its coefficients
+        CO = np.stack([np.stack(cs) for cs in zip(*coef_g)])
 
-        # per-(group, div row, sum unit) injection values from the fresh
-        # attribute data (v term; None when the unit's type differs)
-        n_sum = len(used) - 1
-        ng = len(steps_g)
         if n_sum:
+            # per-(group, div row, sum unit) injection values from the
+            # fresh attribute data (the v term; none when the unit's type
+            # differs from the burst's)
+            ng = len(steps_g)
             vhas = np.zeros((ng, n_sum), dtype=bool)
             vv = np.zeros((ng, d, n_sum))
             for g, s in enumerate(steps_g):
@@ -876,47 +905,115 @@ class FoldExecutor:
                     if vals is not None:
                         vhas[g, pos] = True
                         vv[g, :, pos] = vals[div_g[g]]
-            vh_m = vhas[gof]
-            vv_m = vv[gof]
+            VH = vhas[gof].T                                       # [S, nm]
+            VV = vv[gof].transpose(1, 2, 0)                        # [d, S, nm]
+            # touch[k, g, r, j]: row r's fill column j (0: count, else sum
+            # unit used[j]) has a non-zero coefficient in group g's P[k]
+            ucol = np.asarray(used)
+            zcols = zc0[:, None] + ucol                            # [d, U]
+            touch = CO[:, :, :, zcols.ravel()].any(axis=2).reshape(
+                U, ng, d, U)
+            pj = np.arange(1, U)
+            cross = touch[:, :, :, 1:].copy()
+            cross[pj, :, :, pj - 1] = False
+            if not cross.any():
+                FoldExecutor._fill_stacked(
+                    W, CO, gof, ucol[1:], zcols, ROWF, MFL, base_c, VH, VV,
+                    touch)
+                return True
 
-        ar = np.arange(nm)
+        CO = CO.take(gof, axis=1)                          # [U, nm, b, B]
+        P = np.matmul(CO, W)                               # [U, nm, b, C]
         for r in range(d):
-            i_m = div_g[gof, r]
-            rowf = adj[ar, i_m].astype(float)
-            mfl = mv_m[ar, i_m]
-            zc = 1 + nu + r * nu
-            f_c = (start_m[:, None] * gate_m + W[:, 1]
-                   + np.matmul(rowf[:, None, :], P[0])[:, 0])
-            f_c = np.where(mfl[:, None], f_c, 0.0)
-            self._fill(W, P, coef_m, used, zc, f_c)
+            zc = zc0[r]
+            rowf, mfl = ROWF[r][:, None, :], MFL[r][:, None]
+            f_c = base_c + np.matmul(rowf, P[0])[:, 0]
+            f_c = np.where(mfl, f_c, 0.0)
+            FoldExecutor._fill(W, P, CO, zc, f_c)
             for pos, ui in enumerate(used[1:]):
-                f_s = (W[:, 1 + ui]
-                       + np.matmul(rowf[:, None, :], P[ui])[:, 0])
-                hasv = vh_m[:, pos]
+                f_s = W[:, 1 + ui] + np.matmul(rowf, P[1 + pos])[:, 0]
+                hasv = VH[pos]
                 if hasv.any():
-                    f_s[hasv] = (f_s[hasv]
-                                 + vv_m[hasv, r, pos, None] * f_c[hasv])
-                f_s = np.where(mfl[:, None], f_s, 0.0)
-                self._fill(W, P, coef_m, used, zc + ui, f_s)
+                    f_s[hasv] = f_s[hasv] + VV[r, pos, hasv, None] * f_c[hasv]
+                f_s = np.where(mfl, f_s, 0.0)
+                FoldExecutor._fill(W, P, CO, zc + ui, f_s)
+        return False
 
     @staticmethod
-    def _members(steps_g, gof):
-        """Iterate (group step, member row within the step) in member order."""
-        seen: dict[int, int] = {}
-        for g in gof:
-            g = int(g)
-            i = seen.get(g, 0)
-            seen[g] = i + 1
-            yield steps_g[g], i
+    def _fill_stacked(W, CO, gof, ucol, zcols, ROWF, MFL, base_c, VH, VV,
+                      touch) -> None:
+        """One step per divergent row for every unit: the count column's
+        read and fill, then all sum columns' reads (one batched matvec) and
+        fills.
+
+        ``P`` is held as ``[U, b, nm, C]``: a group's members share its
+        coefficient column, so each fill is one outer product per (unit,
+        group) over the group's contiguous ``(member, C)`` block.  A fill
+        adds ``col * f`` to exactly the (unit, group) blocks whose column
+        is non-zero — the sequential fill's ``col.any()`` members — so the
+        others stay untouched (no ``0 * inf``)."""
+        U, ng, b = CO.shape[:3]
+        nm, C = W.shape[0], W.shape[2]
+        d = len(zcols)
+        P = np.empty((U, b, nm, C))
+        Pr = P.transpose(0, 2, 1, 3)                       # [U, nm, b, C]
+        np.matmul(CO if ng == 1 else CO.take(gof, axis=1), W, out=Pr)
+        P4 = P.reshape(U, b, nm * C)
+        Ps, Prs = P4[1:], Pr[1:]
+        S = U - 1
+        # per (row, unit, group) fill columns and selections
+        COLC = CO[:, :, :, zcols[:, 0]].transpose(3, 0, 1, 2)    # [d,U,ng,b]
+        COLS = np.stack([CO[1 + s][:, :, zcols[:, 1 + s]] for s in range(S)]
+                        ).transpose(3, 0, 1, 2)                  # [d,S,ng,b]
+        SELC = touch[:, :, :, 0].transpose(2, 0, 1)              # [d,U,ng]
+        SELS = touch[1 + np.arange(S), :, :, 1 + np.arange(S)].transpose(
+            2, 0, 1)                                             # [d,S,ng]
+        bounds = np.searchsorted(gof, np.arange(ng + 1))
+        spans = [(g, slice(bounds[g] * C, bounds[g + 1] * C),
+                  slice(bounds[g], bounds[g + 1])) for g in range(ng)]
+        base_s = W[:, 1 + ucol].transpose(1, 0, 2)               # [S,nm,C]
+        inj = (None if not VH.any()
+               else True if VH.all() else VH[:, :, None])
+        for r in range(d):
+            rowf, mfl = ROWF[r][:, None, :], MFL[r][:, None]
+            f_c = base_c + np.matmul(rowf, Pr[0])[:, 0]
+            f_c = np.where(mfl, f_c, 0.0)
+            W[:, zcols[r, 0]] = f_c
+            FoldExecutor._outer_add(P4, COLC[r], SELC[r], f_c, spans)
+            f_s = base_s + np.matmul(rowf, Prs)[:, :, 0]
+            if inj is not None:
+                np.add(f_s, VV[r][:, :, None] * f_c, out=f_s, where=inj)
+            f_s = np.where(mfl, f_s, 0.0)
+            W[:, zcols[r, 1:]] = f_s.transpose(1, 0, 2)
+            FoldExecutor._outer_add(Ps, COLS[r], SELS[r], f_s, spans)
 
     @staticmethod
-    def _fill(W, P, coef_m, used, zcol: int, f: np.ndarray) -> None:
+    def _outer_add(P4, col, sel, f, spans) -> None:
+        """Add the outer product of ``col[k, g]`` (``[b]``) and group
+        ``g``'s block of ``f`` to ``P4[k, :, block]`` for every selected
+        (unit ``k``, group ``g``); ``f`` is ``[nm, C]`` (one value shared by
+        every unit) or ``[K, nm, C]`` (one per unit)."""
+        per_unit = f.ndim == 3
+        for g, cs, ms in spans:
+            fg = (f[:, ms].reshape(len(f), 1, -1) if per_unit
+                  else f[ms].reshape(1, 1, -1))
+            sg = sel[:, g]
+            if sg.all():
+                P4[:, :, cs] += col[:, g, :, None] * fg
+                continue
+            # unit by unit: a fancy index over units would gather and
+            # scatter the whole block
+            for k in np.flatnonzero(sg):
+                P4[k, :, cs] += col[k, g, :, None] * fg[k if per_unit else 0]
+
+    @staticmethod
+    def _fill(W, P, CO, zcol: int, f: np.ndarray) -> None:
         W[:, zcol] = f
-        for u in used:
-            col = coef_m[u][:, :, zcol]
+        for k in range(len(P)):
+            col = CO[k][:, :, zcol]
             sel = col.any(axis=1)
             if sel.any():
-                P[u][sel] += col[sel][:, :, None] * f[sel][:, None, :]
+                P[k][sel] += col[sel][:, :, None] * f[sel][:, None, :]
 
     # -- phase 4: stacked window folds (fold_panes moved behind the executor)
 

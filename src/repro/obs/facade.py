@@ -18,6 +18,7 @@ construction (a window without a compile reads 0, not a missing key) and
 summed by the spans that name the work — ``serve.seal``, ``serve.route``,
 ``serve.wait`` (front-end), ``emit`` (window results), ``execute.count``
 and ``execute.sum`` (a micro-batch flush's two propagate rounds),
+``finalize.fill`` (a divergent fold bucket's event-snapshot fills),
 ``compile`` (every executable built or loaded, from the process-wide
 listener in ``kernels/platform.py``) and ``gc`` (collector pauses, from a
 ``gc.callbacks`` hook that goes with this facade).
@@ -39,8 +40,8 @@ PHASES = ("plan", "execute", "finalize", "fold")
 # registered with tracing on: seconds, except the count of programs
 TIME_COUNTERS = ("serve.seal_s", "serve.route_s", "serve.wait_s",
                  "engine.emit_s", "batch_exec.count_round_s",
-                 "batch_exec.sum_round_s", "kernels.compile_s",
-                 "kernels.programs_built", "host.gc_s")
+                 "batch_exec.sum_round_s", "fold_exec.fill_s",
+                 "kernels.compile_s", "kernels.programs_built", "host.gc_s")
 
 
 def _gc_hook(tracer, counter):
@@ -269,7 +270,9 @@ class Observability:
                     "window_folds": fe.window_folds,
                     "flush_plan_hits": fe.plan_hits,
                     "flush_plan_misses": fe.plan_misses,
-                    "flush_plan_evictions": fe.plan_evictions}
+                    "flush_plan_evictions": fe.plan_evictions,
+                    "fill_stacked": fe.fill_stacked,
+                    "fill_sequential": fe.fill_sequential}
                 for k in ("hits", "misses", "evictions"):
                     # sync the live series to the executor's lifetime total
                     # (they can lag when obs was attached mid-stream)

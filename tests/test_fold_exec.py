@@ -20,7 +20,8 @@ from repro.core.events import EventBatch, StreamSchema
 from repro.core.fold_exec import FoldExecutor, build_fold_schedule, _levelize
 from repro.core.optimizer import AlwaysShare, DynamicPolicy
 from repro.core.pattern import EventType, Kleene, Not, Seq
-from repro.core.query import Pred, Query, Workload, agg_sum, count_star
+from repro.core.query import (Pred, Query, Workload, agg_avg, agg_sum,
+                              count_star)
 from repro.core.service import HamletService
 from repro.eventtime import EventTimeConfig, EventTimeRuntime
 from repro.overload import OverloadConfig
@@ -305,3 +306,189 @@ def test_flush_plan_cache_reused_on_repeated_shapes():
     assert rt.fold_exec.launches == 2 * l1
     for a, b in zip(first, second):
         assert np.array_equal(a, b)
+
+
+# ------------------------------------- stacked event-snapshot fills (SUM)
+
+
+def _ride_stream(epm=600, minutes=1, seed=2**31 + 7):
+    from repro.streams.generator import (RIDESHARING_SCHEMA,
+                                         TenantStreamConfig, tenant_stream)
+
+    return tenant_stream(TenantStreamConfig(
+        schema=RIDESHARING_SCHEMA, n_tenants=4, groups_per_tenant=2,
+        base_events_per_minute=epm, minutes=minutes, burstiness=0.85,
+        type_weights=(1, 1, 6, 1, 1, 1), seed=seed))
+
+
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("plan_cache", [False, True])
+def test_stacked_fill_bitwise_rideshare(K, plan_cache):
+    """The ridesharing deployment's shape — SEQ(Request, Travel+, X) for X
+    in {NOT Pickup, Dropoff, Cancel}, COUNT with SUM(duration) or
+    AVG(speed), a speed predicate of its own on Travel in each copy — folds
+    its divergent buckets in the stacked form, bit for bit as the
+    sequential replay."""
+    from repro.launch.hamlet_service import ridesharing_workload
+
+    wl = ridesharing_workload(7)
+    stream = _ride_stream()
+    t_end = ((int(stream.time.max()) // 5) + 1) * 5
+    want = HamletRuntime(wl, fold_exec=False, plan_cache=False).run(
+        stream, t_end)
+    rt = HamletRuntime(wl, micro_batch=K, plan_cache=plan_cache,
+                       fold_exec=True)
+    _assert_bitwise(rt.run(stream, t_end), want, (K, plan_cache))
+    assert rt.fold_exec.fill_stacked > 0
+
+
+def test_count_only_fills_stay_sequential():
+    """COUNT alone has no sum column to stack: every divergent bucket keeps
+    the column-by-column fill, and the results are the replay's."""
+    wl, stream, t_end = _named_case("ridesharing")
+    want = HamletRuntime(wl, policy=AlwaysShare(), fold_exec=False,
+                         plan_cache=False).run(stream, t_end)
+    rt = HamletRuntime(wl, policy=AlwaysShare(), micro_batch=4,
+                       fold_exec=True)
+    _assert_bitwise(rt.run(stream, t_end), want)
+    assert rt.fold_exec.fill_stacked == 0
+    assert rt.fold_exec.fill_sequential > 0
+
+
+def test_stacked_fill_overflow_matches_replay():
+    """SUM values past float64's range put inf into the sum round's
+    coefficients, and ``0 * inf`` into the fills: the stacked form leaves
+    NaN where the sequential replay does, and the finite windows alike."""
+    wl = Workload(SCHEMA, [
+        Query("q1", Seq(A, Kleene(B)), aggs=(count_star(), agg_sum("B", "v")),
+              within=40, slide=20),
+        Query("q2", Seq(A, Kleene(B)), aggs=(count_star(), agg_sum("B", "v")),
+              preds={"B": [Pred("v", "<", 3)]}, within=40, slide=20),
+        Query("q3", Seq(C, Kleene(B)), aggs=(count_star(), agg_avg("B", "v")),
+              within=40, slide=20),
+    ])
+    evs = ([(0, 1), (2, 1)] + [(1, 1e307), (1, 1)] * 8 + [(0, 1)]
+           + [(1, 2e307), (1, 2), (1, 1)] * 6)
+    batch = _batch(evs)
+    off = HamletRuntime(wl, policy=AlwaysShare(), fold_exec=False,
+                        plan_cache=False).run(batch, 40)
+    rt = HamletRuntime(wl, policy=AlwaysShare(), fold_exec=True,
+                       micro_batch=4)
+    on = rt.run(batch, 40)
+    assert on.keys() == off.keys()
+    assert all(vals_equal(on[k], off[k]) for k in off)
+    vals = [v for r in on.values() for v in r.values()]
+    assert any(np.isnan(v) for v in vals) and any(np.isfinite(v)
+                                                   for v in vals)
+    assert rt.fold_exec.fill_stacked > 0
+
+
+def _ref_fill(W, used, nu, b, div, mvec, epm, coefs, start, sum_v):
+    """One member's event-snapshot fills as ``PaneProcessor.finalize``
+    replays them: a rank-1 update of every unit's ``P`` per filled
+    column, column by column."""
+    P = [c @ W for c in coefs]
+
+    def fill(zcol, f):
+        W[zcol] = f
+        for k, c in enumerate(coefs):
+            col = c[:, zcol]
+            if col.any():
+                P[k] += np.outer(col, f)
+
+    adj = np.tril(np.ones((b, b), dtype=bool), k=-1)
+    if epm is not None:
+        adj &= epm
+    adj &= mvec[None, :]
+    C = W.shape[1]
+    for r, i in enumerate(div):
+        row = adj[i].astype(float)
+        zc = 1 + nu + r * nu
+        f_c = (start * W[0] + W[1] + row @ P[0] if mvec[i]
+               else np.zeros(C))
+        fill(zc, f_c)
+        for pos, ui in enumerate(used[1:]):
+            if mvec[i]:
+                f_s = W[1 + ui] + row @ P[1 + pos]
+                if sum_v[pos] is not None:
+                    f_s = f_s + sum_v[pos][i] * f_c
+            else:
+                f_s = np.zeros(C)
+            fill(zc + ui, f_s)
+
+
+def _hand_bucket(seed, cross=False, inf=False):
+    """Two groups (3 and 2 members) of one divergent bucket: count plus
+    two sum units, coefficients drawn so that a count column feeds every
+    unit but one group's third unit, and each sum column only its own
+    unit's ``P`` — unless ``cross`` puts one non-zero of a sum column into
+    another unit's coefficients."""
+    from types import SimpleNamespace
+
+    rng = np.random.default_rng(seed)
+    nu, used, b, d, C = 3, (0, 1, 2), 6, 3, 5
+    B = 1 + nu + d * nu
+    sizes = (3, 2)
+    gof = np.repeat(np.arange(len(sizes)), sizes)
+    div_g = np.array([[1, 3, 4], [0, 2, 5]])
+    steps_g, coef_g = [], []
+    for g, n in enumerate(sizes):
+        mvec = rng.random((n, b)) < 0.8
+        mvec[:, div_g[g]] |= rng.random((n, d)) < 0.5
+        epm = [None if m % 2 else rng.random((b, b)) < 0.7
+               for m in range(n)]
+        vals = rng.uniform(0.5, 2.0, b)
+        steps_g.append(SimpleNamespace(
+            mvec=mvec, epm=epm, sum_units=[(1, vals), (2, None)]))
+        coefs = []
+        for k, u in enumerate(used):
+            c = rng.standard_normal((b, B))
+            for r in range(d):
+                zc = 1 + nu + r * nu
+                c[: div_g[g, r] + 1, zc:zc + nu] = 0.0
+                for ui in used[1:]:
+                    if ui != u:
+                        c[:, zc + ui] = 0.0
+            if g == 1 and k == 2:
+                c[:, 1 + nu::nu] = 0.0      # the count columns skip unit 2
+            coefs.append(c)
+        coef_g.append(coefs)
+    if cross:
+        coef_g[0][1][5, 1 + nu + nu + 2] = 0.25   # row 1's unit-2 column
+    W = np.zeros((len(gof), B, C))
+    W[:, :1 + nu] = rng.standard_normal((len(gof), 1 + nu, C))
+    if inf:
+        coef_g[1][0][4, 1 + nu] = np.inf
+        W[0, 2, 1] = np.inf
+    start = (rng.random(len(gof)) < 0.5).astype(float)
+    ctx = SimpleNamespace(nu=nu)
+    return ctx, W, used, b, d, div_g, gof, steps_g, coef_g, start
+
+
+@pytest.mark.parametrize("cross, inf, stacked", [
+    (False, False, True),
+    (True, False, False),
+    (False, True, True),
+])
+def test_fill_forms_match_the_member_replay(cross, inf, stacked):
+    """The stacked form runs where no sum column crosses units and the
+    column-by-column form where one does; both equal the per-member replay
+    bit for bit, inf and NaN placement included."""
+    ctx, W, used, b, d, div_g, gof, steps_g, coef_g, start = _hand_bucket(
+        5, cross, inf)
+    want = W.copy()
+    with np.errstate(over="ignore", invalid="ignore"):
+        got = FoldExecutor._fill_snapshots(
+            ctx, W, W[:, 0].copy(), used, b, d, div_g=div_g, gof=gof,
+            steps_g=steps_g, coef_g=coef_g, start_m=start)
+        seen = {}
+        for m, g in enumerate(gof):
+            i = seen[g] = seen.get(g, -1) + 1
+            s = steps_g[g]
+            _ref_fill(want[m], used, ctx.nu, b, div_g[g], s.mvec[i],
+                      s.epm[i], coef_g[g], start[m],
+                      [v for _, v in s.sum_units])
+    assert got is stacked
+    if inf:
+        assert np.isnan(want).any() and np.isinf(want).any()
+    assert np.array_equal(W.view(np.int64), want.view(np.int64))

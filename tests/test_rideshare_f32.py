@@ -14,7 +14,8 @@ per-burst coefficients in float32, window values in the host's float64.
 * the served results agree with the numpy engine in float64, and at a tiny
   size with the brute-force trend enumeration;
 * the sum round's and count round's spans sum to their counters, and the
-  job and negation counters count what the plan holds.
+  job and negation counters count what the plan holds; so do the fold
+  executor's snapshot-fill spans and counters.
 """
 
 import math
@@ -170,6 +171,20 @@ def test_round_counters_equal_their_spans(served):
             c[counter], rel=1e-9, abs=1e-12), span
     assert n["execute.count"] == n["execute.sum"] == len(
         [e for e in evs if e[0] == "X" and e[1] == "flush"])
+
+
+def test_fill_counters_equal_their_spans(served):
+    """One ``finalize.fill`` span per divergent fold bucket, summing to
+    ``fold_exec.fill_s``; the SUM and AVG units' buckets take the stacked
+    fill."""
+    c = served["obs"].registry.collect()
+    xs = [e for e in served["obs"].tracer._snapshot()
+          if e[0] == "X" and e[1] == "finalize.fill"]
+    assert c["fold_exec.fill.stacked"] > 0
+    assert len(xs) == c["fold_exec.fill.stacked"] + c.get(
+        "fold_exec.fill.sequential", 0)
+    assert sum(e[4] for e in xs) / 1e6 == pytest.approx(
+        c["fold_exec.fill_s"], rel=1e-9, abs=1e-12)
 
 
 def test_job_and_negation_counters_match_the_plan(served):

@@ -128,7 +128,8 @@ from .query import AtomicQuery, Workload
 from .template import QueryTemplate, build_template
 
 __all__ = ["ComponentContext", "PaneProcessor", "PaneMicroBatcher",
-           "HamletRuntime", "RunStats", "fold_panes", "vals_equal"]
+           "HamletRuntime", "RunStats", "EmissionRecord", "fold_panes",
+           "vals_equal"]
 
 
 # --------------------------------------------------------------------------
@@ -2095,19 +2096,54 @@ def vals_equal(a: dict, b: dict) -> bool:
     return True
 
 
+@dataclass(frozen=True)
+class EmissionRecord:
+    """One entry on a runtime's output channel: a window it closed, or a
+    revision of one.
+
+    kind        "emit" (first value for this window), "retract" (withdraws
+                the previous value), or "amend" (the replacement value —
+                always immediately preceded by its retract)
+    query       the query the window belongs to: a user query (the
+                overload runtime and the sharded service give
+                :func:`combine_results` of the windows a flush closed),
+                except from :class:`~repro.eventtime.EventTimeRuntime`,
+                which revises atomic queries and combines only in
+                ``results()``
+    group       group partition key
+    w0          window start (ticks)
+    vals        aggregate values ({repr(agg): value})
+    revision    0 for the first emission, incremented per amendment
+    speculative True when emitted past the frontier but before the watermark
+                sealed the window (the value may still be amended)
+    """
+
+    kind: str
+    query: str
+    group: int
+    w0: int
+    vals: dict | None
+    revision: int
+    speculative: bool = False
+
+
 def combine_results(workload: Workload, atomic_results: dict) -> dict:
-    """Combine atomic sub-query results into user-query results (Sec. 5)."""
+    """Combine atomic sub-query results into user-query results (Sec. 5).
+
+    One pass over the input; the output follows its order, so windows of
+    one (query, group) keep the order in which they closed."""
+    by_aqi: dict[int, list] = {}
+    for key in atomic_results:
+        by_aqi.setdefault(key[0], []).append(key)
     out: dict = {}
     for qname, idxs, comb in workload.combines:
         if comb is None:
-            aqi = idxs[0]
-            for (ai, gk, w0), vals in atomic_results.items():
-                if ai == aqi:
-                    out[(qname, gk, w0)] = vals
+            for key in by_aqi.get(idxs[0], ()):
+                out[(qname, key[1], key[2])] = atomic_results[key]
             continue
         left, right = idxs
-        keys = set((gk, w0) for (ai, gk, w0) in atomic_results if ai == left)
-        keys |= set((gk, w0) for (ai, gk, w0) in atomic_results if ai == right)
+        keys = dict.fromkeys((gk, w0) for i in (left, right)
+                             for _, gk, w0 in by_aqi.get(i, ()))
         for gk, w0 in keys:
             lv = atomic_results.get((left, gk, w0), {})
             rv = atomic_results.get((right, gk, w0), {})

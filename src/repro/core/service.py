@@ -61,7 +61,7 @@ import time
 
 import numpy as np
 
-from .engine import HamletRuntime, RunStats, vals_equal
+from .engine import EmissionRecord, HamletRuntime, RunStats, vals_equal
 from .events import EventBatch
 from .query import Query, Workload
 
@@ -365,8 +365,6 @@ class HamletService:
         updates ``self.results``.  Events behind the horizon are expired
         (counted; charged to the overload accountant when attached).
         Returns the new records."""
-        from ..eventtime.revision import EmissionRecord
-
         if not len(late):
             return []
         bound = self._t_done - self._horizon

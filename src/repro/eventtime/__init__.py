@@ -23,7 +23,6 @@ runtime and relaxes that:
 from .config import EventTimeConfig  # noqa: F401
 from .frontier import FrontierSnapshot, RoutedFrontier  # noqa: F401
 from .reorder import ReorderBuffer, ReorderResult, SealedPane  # noqa: F401
-from .revision import (EmissionRecord, EventTimeMetrics,  # noqa: F401
-                       EventTimeRuntime)
+from .revision import EventTimeMetrics, EventTimeRuntime  # noqa: F401
 from .watermark import (BoundedSkew, GroupHeartbeat,  # noqa: F401
                         PercentileAdaptive, WatermarkPolicy, make_watermark)

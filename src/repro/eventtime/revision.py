@@ -49,8 +49,8 @@ from time import perf_counter
 
 import numpy as np
 
-from ..core.engine import (HamletRuntime, PaneMicroBatcher, PaneProcessor,
-                           _Instance, fold_panes, vals_equal)
+from ..core.engine import (EmissionRecord, HamletRuntime, PaneMicroBatcher,
+                           PaneProcessor, _Instance, fold_panes, vals_equal)
 from ..core.events import EventBatch
 from ..core.query import Workload
 from ..obs.metrics import DEPTH_BUCKETS, LAG_BUCKETS
@@ -58,33 +58,7 @@ from .config import EventTimeConfig
 from .reorder import ReorderBuffer
 from .watermark import WM_MIN, make_watermark
 
-__all__ = ["EventTimeRuntime", "EventTimeMetrics", "EmissionRecord"]
-
-
-@dataclass(frozen=True)
-class EmissionRecord:
-    """One entry on the output channel.
-
-    kind        "emit" (first value for this window), "retract" (withdraws
-                the previous value), or "amend" (the replacement value —
-                always immediately preceded by its retract)
-    query       atomic query name (user-level Or/And combination is applied
-                by :meth:`EventTimeRuntime.results`)
-    group       group partition key
-    w0          window start (ticks)
-    vals        aggregate values ({repr(agg): value})
-    revision    0 for the first emission, incremented per amendment
-    speculative True when emitted past the frontier but before the watermark
-                sealed the window (the value may still be amended)
-    """
-
-    kind: str
-    query: str
-    group: int
-    w0: int
-    vals: dict | None
-    revision: int
-    speculative: bool = False
+__all__ = ["EventTimeRuntime", "EventTimeMetrics"]
 
 
 @dataclass

@@ -43,8 +43,9 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..core.engine import (HamletRuntime, PaneMicroBatcher, RunStats,
-                           _Instance, advance_instances, combine_results)
+from ..core.engine import (EmissionRecord, HamletRuntime, PaneMicroBatcher,
+                           RunStats, _Instance, advance_instances,
+                           combine_results)
 from ..core.events import EventBatch
 from ..core.query import Workload
 from ..kernels.platform import thread_compile_seconds, watch_compiles
@@ -227,6 +228,8 @@ class OverloadRuntime:
         self.metrics = OverloadMetrics()
         self._drivers: dict[int, _GroupDriver] = {}
         self._atomic: dict = {}
+        # the windows each landed flush closed, until take_emitted()
+        self._landed: list[dict] = []
         self._t = 0
         self._clock = clock
         self._done_s = 0.0   # completion time on the simulated timeline
@@ -313,7 +316,21 @@ class OverloadRuntime:
     def _await_flush(self) -> None:
         if self._flush_fut is not None:
             fut, self._flush_fut = self._flush_fut, None
-            fut.result()
+            self._landed.append(fut.result())
+
+    def take_emitted(self) -> list[EmissionRecord]:
+        """Hand over, and forget, the windows closed by the flushes that
+        have landed since the last call, in stream order: one ``emit``
+        record per user-query window, with the values ``results()`` holds
+        for it.  A pipelined flush lands here once it has finished, else at
+        the next flush or ``flush_panes()``; this never waits for one."""
+        if self._flush_fut is not None and self._flush_fut.done():
+            self._await_flush()
+        landed, self._landed = self._landed, []
+        return [EmissionRecord("emit", q, g, w0, vals, 0)
+                for closed in landed
+                for (q, g, w0), vals in combine_results(self.workload,
+                                                        closed).items()]
 
     def _drain_backlog(self) -> None:
         backlog, self._backlog = self._backlog, []
@@ -327,17 +344,18 @@ class OverloadRuntime:
             self._flush_fut = self._flush_pool.submit(self._flush_one,
                                                       backlog)
             return
-        self._flush_one(backlog)
+        self._landed.append(self._flush_one(backlog))
 
-    def _flush_one(self, backlog: list) -> None:
+    def _flush_one(self, backlog: list) -> dict:
+        """Execute one micro-batch; returns the atomic windows it closed."""
         c0 = self._clock()
         cs0 = thread_compile_seconds()
         if len(backlog) == 1:
             t0, _n, _keep, _late, kept = backlog[0]
-            self._process(kept, t0)
+            closed = self._process(kept, t0)
         else:
-            self._process_batch([(t0, kept)
-                                 for t0, _n, _k, _l, kept in backlog])
+            closed = self._process_batch([(t0, kept)
+                                          for t0, _n, _k, _l, kept in backlog])
         # the controller acts on pane-processing time (the directly
         # controllable quantity), amortized across the fused micro-batch and
         # less the programs this flush built or loaded: a first use of a
@@ -361,15 +379,17 @@ class OverloadRuntime:
                             LATENCY_MS_BUCKETS)
                 if n > keep_n:
                     obs.count("overload.shed_events", n - keep_n)
+        return closed
 
-    def _process(self, kept: EventBatch, t0: int) -> None:
+    def _process(self, kept: EventBatch, t0: int) -> dict:
         """Process one admitted pane through the group drivers."""
-        self._process_batch([(t0, kept)])
+        return self._process_batch([(t0, kept)])
 
-    def _process_batch(self, panes: list[tuple[int, EventBatch]]) -> None:
+    def _process_batch(self, panes: list[tuple[int, EventBatch]]) -> dict:
         """Fused execution of K admitted panes: plan every (pane, group,
         component) into one micro-batch, drain once — one launch per size
-        bucket per K panes — then finalize and fold in stream order."""
+        bucket per K panes — then finalize and fold in stream order.
+        Returns the atomic windows the panes closed."""
         mb = PaneMicroBatcher(self.rt.executor, k=len(panes),
                               fold_exec=self.rt.fold_exec, obs=self.rt.obs)
         planned: list = []
@@ -386,6 +406,7 @@ class OverloadRuntime:
         mb.drain()
         obs = self.obs
         traced = obs is not None and obs.tracing
+        closed: dict = {}
         for (t0, _kept), per in zip(panes, planned):
             for drv, _pane_ev, pends in per:
                 drv.fold(pends, t0, self.stats)
@@ -394,7 +415,9 @@ class OverloadRuntime:
                            counter="engine.emit_s") if traced
                   else NULL_SPAN):
                 for drv, pane_ev, _pends in per:
-                    drv.emit(pane_ev, t0, self._atomic, self.stats)
+                    drv.emit(pane_ev, t0, closed, self.stats)
+        self._atomic.update(closed)
+        return closed
 
     def _latency_ms(self, t0: int, proc_s: float) -> float:
         ts = self.config.tick_seconds

@@ -14,22 +14,27 @@ The pieces:
 * :class:`~repro.serve.scheduler.ContinuousBatcher` — stages submissions,
   seals pane-complete prefixes against the session watermark, so flushes
   form from whatever is ready instead of a fixed epoch grid;
-* three backend adapters sharing one small interface::
+* one backend interface, over whichever runtime serves::
 
-      ingest(chunk, boundary) -> records|None   # sealed prefix, in order
-      finish(t_end)           -> records|None   # stream end
-      pending_flush()         -> bool           # micro-batch still open?
+      ingest(chunk, boundary) -> records   # sealed prefix, in order
+      finish(t_end)           -> records   # stream end
       results() / stats() / shutdown()
+
+  where ``records`` are :class:`~repro.core.engine.EmissionRecord`
+  values, one for each window the runtime closed (or revised) in that
+  call.  The front-end routes them to the subscribed sessions and
+  nothing else: the runtime that closes a window is the one that says so.
 
   - ``overload``  — one :class:`OverloadRuntime` (admission + shedding +
     micro-batched pane pipeline, optional ``pipeline_flush`` overlap);
-    emissions are computed by diffing ``results()`` snapshots;
-  - ``sharded``   — a :class:`ShardedHamletService`; with
-    ``ShardServiceConfig.parallel`` the shard drive cycles run on a
-    thread pool and the watermark aligner is a real rendezvous barrier;
-  - ``eventtime`` — an :class:`EventTimeRuntime`; its
-    :class:`EmissionRecord` channel (emit/retract/amend) is forwarded
-    verbatim, giving sessions a true retraction channel under disorder.
+    one ``emit`` per user-query window, as its flush lands;
+  - ``sharded``   — a :class:`ShardedHamletService`, the union of its
+    shards' records; with ``ShardServiceConfig.parallel`` the shard drive
+    cycles run on a thread pool and the watermark aligner is a real
+    rendezvous barrier;
+  - ``eventtime`` — an :class:`EventTimeRuntime`, whose channel also
+    carries retract/amend revisions: a true retraction channel under
+    disorder.
 
 Determinism contract: submissions are seq-stamped per session
 (``sid << 32 | counter``), staged events merge via the canonical
@@ -57,7 +62,6 @@ from typing import Callable
 
 import numpy as np
 
-from ..core.engine import vals_equal
 from ..core.events import EventBatch
 from ..obs.metrics import (SERVE_LATENCY_MS_BUCKETS, Histogram,
                            serve_latency_series)
@@ -76,7 +80,6 @@ class _OverloadBackend:
     """Adapter over one shared :class:`OverloadRuntime`."""
 
     name = "overload"
-    retracts = False
 
     def __init__(self, workload, cfg=None, policy=None, backend="np",
                  obs=None):
@@ -93,16 +96,13 @@ class _OverloadBackend:
             self.rt.offer(chunk)
         while self.rt.t_now + self.pane <= boundary:
             self.rt.step_pane()
-        return None
+        return self.rt.take_emitted()
 
     def finish(self, t_end):
         while self.rt.t_now + self.pane <= t_end:
             self.rt.step_pane()
         self.rt.flush_panes()
-        return None
-
-    def pending_flush(self):
-        return len(self.rt._backlog) > 0
+        return self.rt.take_emitted()
 
     def results(self):
         return self.rt.results()
@@ -120,7 +120,6 @@ class _ShardedBackend:
     ``parallel=True`` thread-pool shard drives)."""
 
     name = "sharded"
-    retracts = False
 
     def __init__(self, workload, cfg, backend="np", obs=None):
         # (shard workers own their observability via cfg.obs; the serving
@@ -130,25 +129,17 @@ class _ShardedBackend:
         self.pane = self.svc.pane
         self.controller = None          # admission lives per shard
         self.accountant = None
-        self._closed = False
 
     def ingest(self, chunk, boundary):
         # The scheduler's watermark is a stronger order promise than the
         # router's max-seen heuristic: honour it so shards seal panes the
         # routed chunk alone would leave open.
         self.svc.promise(boundary - 1)
-        self.svc.ingest(chunk)
-        return None
+        return self.svc.ingest(chunk)
 
     def finish(self, t_end):
         self.svc.promise(t_end - 1)
-        if not self._closed:
-            self.svc.close()
-            self._closed = True
-        return None
-
-    def pending_flush(self):
-        return any(w.pending_flush() for w in self.svc.workers)
+        return self.svc.close()
 
     def results(self):
         return self.svc.results()
@@ -157,20 +148,16 @@ class _ShardedBackend:
         return {"backend": self.name, **self.svc.collect()}
 
     def shutdown(self):
-        if not self._closed:
-            self.svc.close()
-            self._closed = True
+        self.svc.close()
 
 
 class _EventTimeBackend:
-    """Adapter over an :class:`EventTimeRuntime` — the only backend with a
-    native emission channel (including retract/amend revisions), so
-    deliveries forward its :class:`EmissionRecord` stream verbatim.
-    Note the records carry *atomic* query names (revision granularity);
-    final ``results()`` are combined to user queries as everywhere else."""
+    """Adapter over an :class:`EventTimeRuntime`, the backend whose records
+    include retract/amend revisions.  They carry *atomic* query names
+    (revision granularity); final ``results()`` are combined to user
+    queries as everywhere else."""
 
     name = "eventtime"
-    retracts = True
 
     def __init__(self, workload, cfg=None, policy=None, backend="np",
                  micro_batch=1, obs=None):
@@ -190,9 +177,6 @@ class _EventTimeBackend:
 
     def finish(self, t_end):
         return self.rt.flush(t_end)
-
-    def pending_flush(self):
-        return False
 
     def results(self):
         return self.rt.results()
@@ -275,11 +259,13 @@ class ServingFrontend:
         self._admission = (SessionAdmission(self._backend.controller,
                                             self._backend.accountant)
                            if session_admission else None)
-        # user-query readiness horizon: a window (q, g, w0) is complete
-        # once the seal boundary reaches w0 + within(q)
-        self._within = {qname: max(workload.atomic[i].within for i in idxs)
-                        for qname, idxs, _ in workload.combines}
-        self._atomic_within = {q.name: q.within for q in workload.atomic}
+        # readiness horizon, by atomic and by user query name: a window
+        # (q, g, w0) is complete once the seal boundary reaches
+        # w0 + within(q)
+        self._within = {q.name: q.within for q in workload.atomic}
+        self._within.update(
+            (qname, max(workload.atomic[i].within for i in idxs))
+            for qname, idxs, _ in workload.combines)
 
         self._lock = threading.Lock()        # staging + session registry
         self._pump_lock = threading.Lock()   # one flush former at a time
@@ -289,11 +275,8 @@ class ServingFrontend:
         self._drained = False
 
         # delivery bookkeeping (guarded by the pump lock)
-        self._published: dict = {}           # (q, g, w0) -> vals
-        self._revno: dict = {}               # (q, g, w0) -> revision counter
         self._seal_bounds: list[int] = []    # sorted seal boundaries ...
         self._seal_walls: list[float] = []   # ... and their wall clocks
-        self._dirty = False                  # panes stepped since last diff
 
         # observability (histograms live here; mirrored into obs when set)
         self._lat_all = Histogram("serve.latency_ms.all",
@@ -415,13 +398,13 @@ class ServingFrontend:
     def _pump_locked(self, upto: int | None = None,
                      loop: bool = False) -> int:
         """One pump cycle.  With tracing on it is named in spans: a cycle
-        that seals or routes anything records ``serve.seal``,
-        ``serve.flush`` (the backend's ingest) and ``serve.route``; on the
+        that seals records ``serve.seal``, ``serve.flush`` (the backend's
+        ingest) and, when the flush closed windows, ``serve.route``; on the
         pump loop (``loop``), the time between two such cycles, idle cycles
         and sleeps included, is one ``serve.wait`` span."""
         tr = self._tracing
         obs = self.obs
-        chunk = records = None
+        chunk = None
         with self._lock:
             if self._batcher.ready(upto):
                 if loop:
@@ -438,25 +421,11 @@ class ServingFrontend:
                                  "boundary": boundary}) if tr
                   else NULL_SPAN):
                 records = self._backend.ingest(chunk, boundary)
+            self._route_records(records)
             n = len(chunk)
-            self._dirty = True
-        # diff-based backends emit only on flush boundaries: collect when
-        # the micro-batch has actually flushed, never force a partial one
-        diff = (not self._backend.retracts and self._dirty
-                and not self._backend.pending_flush())
-        if records or diff:
             if loop:
-                self._end_wait()
-            with (obs.span("serve.route", cat="serve", annotate=True,
-                           counter="serve.route_s") if tr else NULL_SPAN):
-                if records:
-                    self._route_records(records)
-                if diff:
-                    self._route_diff()
-                    self._dirty = False
+                self._begin_wait()
         self.pump_cycles += 1
-        if loop and (chunk is not None or records or diff):
-            self._begin_wait()
         return n
 
     def _begin_wait(self) -> None:
@@ -512,15 +481,7 @@ class ServingFrontend:
                 t_end = ((t_hi + self.pane - 1) // self.pane) * self.pane
             self._pump_locked(upto=t_end)
             self._log_seal(t_end)
-            records = self._backend.finish(t_end)
-            with (self.obs.span("serve.route", cat="serve", annotate=True,
-                                counter="serve.route_s") if self._tracing
-                  else NULL_SPAN):
-                if records:
-                    self._route_records(records)
-                if not self._backend.retracts:
-                    self._route_diff()
-                    self._dirty = False
+            self._route_records(self._backend.finish(t_end))
             res = self._backend.results()
             with self._lock:
                 for h in self._sessions.values():
@@ -541,37 +502,21 @@ class ServingFrontend:
         i = bisect.bisect_left(self._seal_bounds, close_t)
         return self._seal_walls[i] if i < len(self._seal_bounds) else now
 
-    def _route_diff(self) -> None:
-        res = self._backend.results()
-        now = self._clock()
-        for key, vals in res.items():
-            old = self._published.get(key)
-            if old is not None and vals_equal(old, vals):
-                continue
-            q, g, w0 = key
-            rev = self._revno.get(key, -1) + 1
-            self._revno[key] = rev
-            ready = self._ready_wall(w0 + self._within[q], now)
-            lat = max(0.0, (now - ready) * 1e3)
-            if old is not None:
-                self._deliver(Delivery("retract", q, g, w0, old, rev - 1,
-                                       lat), count=False)
-                kind = "amend"
-            else:
-                kind = "emit"
-            self._published[key] = vals
-            self._deliver(Delivery(kind, q, g, w0, vals, rev, lat))
-
     def _route_records(self, records) -> None:
-        now = self._clock()
-        for r in records:
-            within = self._atomic_within.get(r.query,
-                                             self._within.get(r.query, 0))
-            ready = self._ready_wall(r.w0 + within, now)
-            lat = max(0.0, (now - ready) * 1e3)
-            self._deliver(Delivery(r.kind, r.query, r.group, r.w0, r.vals,
-                                   r.revision, lat),
-                          count=r.kind != "retract")
+        """Deliver a backend's records, inside ``serve.route`` when traced.
+        A window's latency runs from the first seal that covered it."""
+        if not records:
+            return
+        with (self.obs.span("serve.route", cat="serve", annotate=True,
+                            counter="serve.route_s") if self._tracing
+              else NULL_SPAN):
+            now = self._clock()
+            for r in records:
+                ready = self._ready_wall(r.w0 + self._within[r.query], now)
+                lat = max(0.0, (now - ready) * 1e3)
+                self._deliver(Delivery(r.kind, r.query, r.group, r.w0,
+                                       r.vals, r.revision, lat),
+                              count=r.kind != "retract")
 
     def _deliver(self, d: Delivery, count: bool = True) -> None:
         tenant = d.group // self.groups_per_tenant

@@ -166,8 +166,8 @@ def _worker_main(conn, shard_id, workload, cfg, policy,
                 payload = w.summary()
             elif op == "controller_state":
                 payload = w.controller_state()
-            elif op == "pending_flush":
-                payload = w.pending_flush()
+            elif op == "take_emitted":
+                payload = w.take_emitted()
             elif op == "obs_registry":
                 payload = w.obs.registry if w.obs is not None else None
             elif op == "set":
@@ -258,8 +258,10 @@ class ProcShardWorker:
                 f"shard process {self.shard_id} failed: {err}\n{tb}")
         return payload
 
-    _SNAPSHOT_OPS = ("results", "stats", "accountant", "summary",
-                     "controller_state", "pending_flush", "obs_registry")
+    # "results" flushes the last micro-batch, so the "take_emitted" after
+    # it holds the records of the final flush
+    _SNAPSHOT_OPS = ("results", "take_emitted", "stats", "accountant",
+                     "summary", "controller_state", "obs_registry")
 
     def _rpc(self, op, *args):
         if self._final is not None:
@@ -339,8 +341,11 @@ class ProcShardWorker:
     def controller_state(self):
         return self._rpc("controller_state")
 
-    def pending_flush(self) -> bool:
-        return self._rpc("pending_flush")
+    def take_emitted(self) -> list:
+        if self._final is not None:
+            # after shutdown: the final flush's records, handed over once
+            return self._final.pop("take_emitted", [])
+        return self._rpc("take_emitted")
 
     def obs_registry(self):
         return self._rpc("obs_registry")

@@ -324,8 +324,10 @@ class ShardWorker:
     def controller_state(self):
         return self.rt.controller.state()
 
-    def pending_flush(self) -> bool:
-        return len(self.rt._backlog) > 0
+    def take_emitted(self) -> list:
+        """The records of the windows this shard's landed flushes closed
+        since the last call (``OverloadRuntime.take_emitted``)."""
+        return self.rt.take_emitted()
 
     def obs_registry(self):
         return self.obs.registry if self.obs is not None else None
@@ -416,6 +418,7 @@ class ShardedHamletService:
                         for qname, idxs, _ in workload.combines}
         self._max_seen = -1
         self._moves: list[_PendingMove] = []
+        self._emitted: list = []     # records taken early by a rebalance
         self._closed = False
         self.chunks = 0
         self.router_busy_s = 0.0
@@ -444,8 +447,9 @@ class ShardedHamletService:
         routed chunks alone would leave open."""
         self._max_seen = max(self._max_seen, int(t))
 
-    def ingest(self, chunk: EventBatch) -> None:
-        """Route one arrival chunk and run a drive cycle."""
+    def ingest(self, chunk: EventBatch) -> list:
+        """Route one arrival chunk and run a drive cycle.  Returns the
+        records of the windows the cycle's flushes closed."""
         if self._closed:
             raise RuntimeError("service is closed")
         c0 = self._clock()
@@ -464,13 +468,24 @@ class ShardedHamletService:
         if self._pool is not None or self._mode == "process":
             # offers ride the worker tasks: ingest + drive overlap per shard
             self._drive(subs, hb)
-            return
-        for w, sub in zip(self.workers, subs):
-            w.offer(sub, self._max_seen)
-        if hb is not None:
-            for w in self.workers:
-                w.heartbeat(hb)
-        self._drive()
+        else:
+            for w, sub in zip(self.workers, subs):
+                w.offer(sub, self._max_seen)
+            if hb is not None:
+                for w in self.workers:
+                    w.heartbeat(hb)
+            self._drive()
+        return self._take_emitted()
+
+    def _take_emitted(self) -> list:
+        """The union of the shards' records.  Groups are disjoint per shard
+        and a rebalanced group's windows close on one side of its boundary
+        only, so no key comes twice; per (query, group) the records keep
+        the order in which the windows closed."""
+        out, self._emitted = self._emitted, []
+        for w in self.workers:
+            out.extend(w.take_emitted())
+        return out
 
     def _route(self, chunk: EventBatch) -> list[EventBatch]:
         if not len(chunk):
@@ -551,11 +566,12 @@ class ShardedHamletService:
         finally:
             self.aligner.arrive(w.frontier())
 
-    def close(self) -> None:
+    def close(self) -> list:
         """Seal the stream: flush reorder buffers, drive every shard to the
-        final pane boundary (releasing rebalance barriers on the way)."""
+        final pane boundary (releasing rebalance barriers on the way).
+        Returns the records of the windows closed since the last ingest."""
         if self._closed:
-            return
+            return []
         self._closed = True
         t_end = ((self._max_seen + self.pane) // self.pane) * self.pane
         for w in self.workers:
@@ -576,6 +592,7 @@ class ShardedHamletService:
         if self._pool is not None:
             self._pool.shutdown(wait=True)
             self._pool = None
+        return self._take_emitted()
 
     # ---------------------------------------------------------- rebalance
 
@@ -636,6 +653,9 @@ class ShardedHamletService:
         src, dst = self.workers[mv.src], self.workers[mv.dst]
         src.rt.flush_panes()
         dst.rt.flush_panes()
+        # the group's windows so far closed on the source: take them now,
+        # so none is handed over after a later one closed on the target
+        self._emitted.extend(src.take_emitted())
         drv = src.rt._drivers.pop(mv.group, None)
         if drv is not None:
             moved = _GroupDriver(dst.rt.rt, mv.group, mv.boundary)

@@ -153,7 +153,7 @@ def test_process_worker_shutdown_is_idempotent_and_clean():
     w.shutdown()
     w.shutdown()                      # second call is a no-op
     assert w.results() == {}          # snapshot survives the process
-    assert w.pending_flush() is False
+    assert w.take_emitted() == []      # no record outlives the process
     assert not mp.active_children()
     leaked = [t for t in threading.enumerate()
               if t not in before and t.is_alive()]

@@ -10,7 +10,7 @@ import pytest
 
 from repro.core.engine import HamletRuntime, vals_equal
 from repro.core.events import EventBatch
-from repro.core.pattern import EventType, Kleene, Seq
+from repro.core.pattern import And, EventType, Kleene, Or, Seq
 from repro.core.query import Query, Workload
 from repro.eventtime.config import EventTimeConfig
 from repro.overload.config import OverloadConfig
@@ -64,11 +64,12 @@ def _by_tenant(stream, n_tenants, groups_per_tenant=2):
     return parts
 
 
-def _trickle(fe, parts, seed, chunk=40, pump_p=0.5):
+def _trickle(fe, parts, seed, chunk=40, pump_p=0.5, groups=None):
     """Random seeded interleaving: sessions submit chunks in shuffled
     order, pumping stochastically along the way."""
     rng = np.random.default_rng(seed)
-    sessions = [fe.open_session(tenant=t) for t in range(len(parts))]
+    sessions = [fe.open_session(tenant=t, groups=groups)
+                for t in range(len(parts))]
     cursors = [0] * len(parts)
     while any(c < len(p) for c, p in zip(cursors, parts)):
         t = int(rng.integers(0, len(parts)))
@@ -175,6 +176,76 @@ def test_session_ordering_per_group():
             assert len(set(w0s)) == len(w0s), key
         assert s.drained
     assert total == len(fe.results())
+
+
+def _or_and_workload():
+    """The ridesharing workload plus an Or and an And query, each over
+    two atomic parts with disjoint types."""
+    wl, _ = _dataset("ridesharing")
+    req, acc, travel = (EventType(t) for t in ("Request", "Accept",
+                                               "Travel"))
+    return Workload(wl.schema, list(wl.queries) + [
+        Query("qor", Or(Kleene(travel), Kleene(req)), within=20, slide=10),
+        Query("qand", And(Seq(acc, Kleene(travel)), Kleene(req)),
+              within=20, slide=10)])
+
+
+_MB4 = OverloadConfig(shed_policy="none", micro_batch=4)
+_EMISSION_CASES = {
+    "overload": ({"backend": "overload", "overload": _MB4}, False),
+    "overload-or-and": ({"backend": "overload", "overload": _MB4}, True),
+    **{f"sharded-{mode}": ({"backend": "sharded",
+                            "shard_cfg": ShardServiceConfig(
+                                n_shards=2, admission="none",
+                                parallel=parallel, overload=_MB4)}, False)
+       for mode, parallel in (("serial", False), ("thread", "thread"),
+                              ("process", "process"))},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EMISSION_CASES))
+def test_runtime_records_deliver_each_window_once(case):
+    """Every backend hands the front-end the windows it closed: each
+    session subscribed to everything receives every drained result
+    exactly once, as a bitwise-equal ``emit`` — never a retract or an
+    amend — in nondecreasing ``w0`` per (query, group)."""
+    kw, or_and = _EMISSION_CASES[case]
+    wl, stream = _dataset("ridesharing")
+    if or_and:
+        wl = _or_and_workload()
+    fe = ServingFrontend(wl, groups_per_tenant=2, **kw)
+    sessions = _trickle(fe, _by_tenant(stream, 3), seed=7, groups="all")
+    res = fe.drain()
+    assert res
+    for s in sessions:
+        got, w0s = {}, {}
+        for d in s.poll():
+            assert d.kind == "emit" and d.revision == 0, d
+            key = (d.query, d.group, d.w0)
+            assert key not in got, ("delivered twice", key)
+            got[key] = d.vals
+            w0s.setdefault(key[:2], []).append(d.w0)
+        _assert_same(got, res, case)
+        for qg, seq in w0s.items():
+            assert seq == sorted(seq), qg
+
+
+@pytest.mark.parametrize("backend", ["overload", "sharded"])
+def test_pump_never_reads_backend_results(backend):
+    """Routing a flush costs the windows it closed, not the run's: no pump
+    rebuilds ``results()``; only ``drain`` reads them, once."""
+    kw, _ = _EMISSION_CASES["overload" if backend == "overload"
+                            else "sharded-serial"]
+    wl, stream = _dataset("ridesharing")
+    fe = ServingFrontend(wl, groups_per_tenant=2, **kw)
+    calls = []
+    results = fe._backend.results
+    fe._backend.results = lambda: calls.append(1) or results()
+    _trickle(fe, _by_tenant(stream, 3), seed=4, pump_p=1.0)
+    assert fe.pump_cycles > 0 and fe.deliveries > 0
+    assert calls == []
+    fe.drain()
+    assert calls == [1]
 
 
 def test_retraction_channel_delivery():

@@ -160,16 +160,51 @@ def test_rebalance_is_exact():
     src = svc.placement.shard_of(group)
     dst = 1 - src
     boundary = None
+    records = []
     for t0 in range(0, t_hi, svc.pane):
-        svc.ingest(stream.time_slice(t0, t0 + svc.pane))
+        records += svc.ingest(stream.time_slice(t0, t0 + svc.pane))
         if boundary is None and t0 >= t_hi // 2:
             boundary = svc.plan_rebalance(group, dst)
-    svc.close()
+    records += svc.close()
     assert boundary is not None and boundary % svc.pane == 0
     assert svc.placement.overrides == {group: dst}
     assert svc.placement.shard_of(group) == dst
     assert not svc._moves, "move never committed"
     _assert_same_results(baseline, svc.results())
+    # the moved group's windows are handed over once each, in close order
+    emitted = {(r.query, r.group, r.w0): r.vals for r in records}
+    assert len(emitted) == len(records)
+    _assert_same_results(baseline, emitted)
+    moved = [(r.query, r.w0) for r in records if r.group == group]
+    for q in {q for q, _ in moved}:
+        w0s = [w0 for qq, w0 in moved if qq == q]
+        assert w0s == sorted(w0s), q
+
+
+def test_rebalance_committed_in_close_hands_over_in_close_order():
+    """A move held back by a stalled source shard commits while ``close``
+    drives the shards out, and the target then closes the group's later
+    windows in the same call: the source's windows of the group are still
+    handed over first, whatever the shards' order."""
+    wl, stream = _dataset("ridesharing")
+    t_hi = int(stream.time.max()) + 1
+    svc = ShardedHamletService(wl, _cfg(2))
+    group = next(g for g in range(6) if svc.placement.shard_of(g) == 1)
+    records = []
+    for t0 in range(0, t_hi, svc.pane):
+        records += svc.ingest(stream.time_slice(t0, t0 + svc.pane))
+        if t0 == t_hi // 2 // svc.pane * svc.pane:
+            svc.plan_rebalance(group, 0)
+            svc.workers[1].throttle = 0      # the source stalls
+    assert svc._moves, "the move committed before close"
+    records += svc.close()
+    assert svc.placement.shard_of(group) == 0
+    emitted = {(r.query, r.group, r.w0): r.vals for r in records}
+    assert len(emitted) == len(records)
+    _assert_same_results(svc.results(), emitted)
+    for q in {r.query for r in records}:
+        w0s = [r.w0 for r in records if r.group == group and r.query == q]
+        assert w0s == sorted(w0s), q
 
 
 def test_rebalance_to_same_shard_is_noop():
